@@ -1,8 +1,9 @@
 """Dense-vs-sharded FusionEngine crossover: measured, not asserted.
 
 For a grid of dimensions d, times the cold factor+solve and the cached
-(serving) solve on both backends over an 8-device host-platform CPU mesh and
-records the ratio per d plus the first d where the sharded solve wins
+(serving) solve on both backends over a mesh of every device (8 host-platform
+devices on the CPU, every attached chip on an accelerator) and records the
+ratio per d plus the first d where the sharded solve wins
 (``crossover_d``; null when the dense path wins everywhere measured — the
 expected outcome on a single host, where psums are memcpys and the dense
 backend has no communication at all; the table is the point, so capacity
@@ -10,10 +11,12 @@ planning reads data instead of folklore). Every row also carries an
 equivalence check against ``core.fusion.solve_ridge`` and a sharding-spec
 check that the fused Gram stayed block-sharded.
 
-jax locks the device count at first init, so the measurement runs in a child
-process that sets ``--xla_force_host_platform_device_count=8`` before
-importing jax; ``run()`` (the benchmarks.run entry) spawns the child and
-reads back the JSON it writes to experiments/repro/.
+On the CPU, jax locks the device count at first init, so the measurement
+runs in a child process that sets ``--xla_force_host_platform_device_count=8``
+before importing jax; ``run()`` (the benchmarks.run entry) spawns the child
+and reads back the JSON it writes to experiments/repro/. On an accelerator
+``run()`` measures in its own process: a chip belongs to one process at a
+time, so a child of a parent that touched jax could not reach it.
 
 Usage: PYTHONPATH=src:. python benchmarks/sharded_fusion_bench.py [--smoke]
 """
@@ -35,7 +38,7 @@ _OUT = _REPO / "experiments" / "repro"
 _JSON = _OUT / "sharded_fusion_bench.json"
 
 
-def _child(smoke: bool) -> None:
+def _measure(smoke: bool) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -46,8 +49,7 @@ def _child(smoke: bool) -> None:
     from repro.launch import mesh as mesh_lib
     from repro.server import FusionEngine, ShardedBackend
 
-    assert jax.device_count() == 8, jax.device_count()
-    mesh = mesh_lib.make_cpu_mesh(8)
+    mesh = mesh_lib.make_device_mesh()
     dims = [96, 192] if smoke else [128, 256, 384, 512, 768]
     reps = 3 if smoke else 7
 
@@ -116,7 +118,13 @@ def _child(smoke: bool) -> None:
 
 
 def run(smoke: bool = False) -> list[dict]:
-    """Spawn the 8-device child, surface its output, return its claims."""
+    """Measure (in-process on an accelerator, in the 8-device child on the
+    CPU), surface the output, return the claims."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        _measure(smoke)
+        return json.loads(_JSON.read_text())["claims"]
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = f"{_REPO / 'src'}:{_REPO}"
@@ -141,7 +149,7 @@ if __name__ == "__main__":
                          "(expects the 8-device XLA flag already set)")
     args = ap.parse_args()
     if args.child:
-        _child(args.smoke)
+        _measure(args.smoke)
         sys.exit(0)
     failed = [c for c in run(smoke=args.smoke) if not c["pass"]]
     sys.exit(1 if failed else 0)

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import projection, rff
-from repro.core.sufficient_stats import SuffStats
+from repro.core.sufficient_stats import MATMUL_PRECISION, SuffStats
 
 KINDS = ("sketch", "rff")
 
@@ -111,7 +111,8 @@ class FeatureMap:
         """
         # yty = Σ b² is featurization-invariant (targets never featurize):
         # feature-space inference uses the same residual second moment.
-        yty = jnp.einsum("n,n->", b, b).astype(jnp.asarray(A).dtype)
+        yty = jnp.einsum("n,n->", b, b, precision=MATMUL_PRECISION
+                         ).astype(jnp.asarray(A).dtype)
         if use_pallas:
             from repro.kernels import ops
 
@@ -150,8 +151,8 @@ class FeatureMap:
     def predict(self, X: jax.Array, w: jax.Array) -> jax.Array:
         """Predictions from *served* (lifted) weights on raw rows X."""
         if self.kind == "sketch":
-            return X @ w
-        return self(X) @ w
+            return jnp.matmul(X, w, precision=MATMUL_PRECISION)
+        return jnp.matmul(self(X), w, precision=MATMUL_PRECISION)
 
     def error_bound(self, w_norm: float, c: float = 1.0) -> float | None:
         """Prop 3's c·sqrt(d/m)·||w|| shape for the sketch; None for RFF

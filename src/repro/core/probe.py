@@ -20,7 +20,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import fusion
 from repro.core.sufficient_stats import SuffStats, compute_stats
@@ -84,8 +83,8 @@ def one_shot_probe(
         s = _feature_stats(feats, y_k)
         return jax.tree.map(lambda v: jax.lax.psum(v, client_axes), s)
 
-    fused = shard_map(local, mesh=mesh, in_specs=(row, row), out_specs=P(),
-                      check_rep=False)(inputs, targets)
+    fused = jax.shard_map(local, mesh=mesh, in_specs=(row, row),
+                          out_specs=P(), check_vma=False)(inputs, targets)
     return ProbeResult(solve_head(fused, sigma), fused, sigma)
 
 

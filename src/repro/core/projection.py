@@ -14,7 +14,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.sufficient_stats import SuffStats, compute_stats
+from repro.core.sufficient_stats import (MATMUL_PRECISION, SuffStats,
+                                         compute_stats)
 
 
 def make_projection(key: jax.Array, d: int, m: int, dtype=jnp.float32) -> jax.Array:
@@ -26,7 +27,7 @@ def make_projection(key: jax.Array, d: int, m: int, dtype=jnp.float32) -> jax.Ar
 
 def project_data(A: jax.Array, R: jax.Array) -> jax.Array:
     """Client-side feature sketch A~ = A R  (n_k x m)."""
-    return A @ R
+    return jnp.matmul(A, R, precision=MATMUL_PRECISION)
 
 
 def projected_stats(A: jax.Array, b: jax.Array, R: jax.Array) -> SuffStats:
@@ -36,7 +37,7 @@ def projected_stats(A: jax.Array, b: jax.Array, R: jax.Array) -> SuffStats:
 
 def lift(v: jax.Array, R: jax.Array) -> jax.Array:
     """Map the sketch-space solution back: w~ = R v (for x^T R v predictions)."""
-    return R @ v
+    return jnp.matmul(R, v, precision=MATMUL_PRECISION)
 
 
 def upload_floats(d: int, m: int | None = None) -> int:

@@ -16,7 +16,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.core.sufficient_stats import SuffStats, compute_stats
+from repro.core.sufficient_stats import (MATMUL_PRECISION, SuffStats,
+                                         compute_stats)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,7 +33,8 @@ class RFFMap:
 
     def __call__(self, X: jax.Array) -> jax.Array:
         D = self.num_features
-        return jnp.sqrt(2.0 / D) * jnp.cos(X @ self.W + self.c)
+        Z = jnp.matmul(X, self.W, precision=MATMUL_PRECISION)
+        return jnp.sqrt(2.0 / D) * jnp.cos(Z + self.c)
 
 
 def make_rff(key: jax.Array, d: int, num_features: int, lengthscale: float = 1.0,

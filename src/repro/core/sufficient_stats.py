@@ -27,7 +27,13 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+
+#: Precision of every matmul on the statistic, solve and inference paths
+#: (the Gram kernels, compute_stats, the factor updates, predictions and the
+#: inference algebra). A TPU's default f32 matmul runs one bf16 pass, ~3
+#: significant digits: far short of the f32 statistics exact fusion promises.
+#: HIGHEST is full f32 on the chip and a no-op on the CPU.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
 
 
 @jax.tree_util.register_dataclass
@@ -125,10 +131,13 @@ def compute_stats(A: jax.Array, b: jax.Array, *, use_pallas: bool = False) -> Su
         gram, moment = kernel_ops.gram_moment(A, b)
     else:
         acc = jnp.float32 if A.dtype in (jnp.bfloat16, jnp.float16) else A.dtype
-        gram = jnp.einsum("ni,nj->ij", A, A, preferred_element_type=acc)
-        moment = jnp.einsum("ni,n->i", A, b, preferred_element_type=acc)
+        gram = jnp.einsum("ni,nj->ij", A, A, preferred_element_type=acc,
+                          precision=MATMUL_PRECISION)
+        moment = jnp.einsum("ni,n->i", A, b, preferred_element_type=acc,
+                            precision=MATMUL_PRECISION)
     acc = jnp.float32 if b.dtype in (jnp.bfloat16, jnp.float16) else b.dtype
-    yty = jnp.einsum("n,n->", b, b, preferred_element_type=acc)
+    yty = jnp.einsum("n,n->", b, b, preferred_element_type=acc,
+                     precision=MATMUL_PRECISION)
     yty = yty.astype(gram.dtype)
     return SuffStats(gram=gram, moment=moment,
                      count=jnp.asarray(A.shape[0], jnp.int32), yty=yty)
@@ -254,12 +263,12 @@ def distributed_stats(
     if participation is None:
         participation = jnp.ones((n_clients,), jnp.float32)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(row_spec, row_spec, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(A, b, participation)
 

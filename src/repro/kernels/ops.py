@@ -1,10 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels (padding, layout, dispatch).
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python for correctness validation; on a real TPU backend
-``interpret=False`` compiles to Mosaic. ``use_pallas`` config flags route the
-model/core code here; the default XLA paths in core/ and models/ are the
-numerical references.
+On a TPU backend the kernels compile to Mosaic; on the CPU backend they run
+in interpret mode (the kernel body runs in Python, for correctness
+validation). Any other backend is an error, never a silent interpreter
+fallback. ``use_pallas`` config flags route the model/core code here; the
+default XLA paths in core/ and models/ are the numerical references.
 
 ``pack_lower``/``unpack_lower`` (the Theorem-4 triangular wire codec for
 client Gram uploads) also live here: they are jitted static-index
@@ -24,8 +24,25 @@ from repro.kernels import gram as gram_kernel
 from repro.kernels import swa_flash as swa_kernel
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def _interpret(interpret: bool | None) -> bool:
+    """Resolve a kernel's ``interpret`` flag against the running backend.
+
+    ``None`` compiles on a TPU and interprets on the CPU. The interpreter is
+    refused everywhere but the CPU: on an accelerator it would run the
+    kernel body in Python while reporting the accelerator's name. An
+    explicit ``False`` is always allowed — lowering for a described TPU
+    (``jax.experimental.topologies``) happens on a CPU-backed process.
+    """
+    backend = jax.default_backend()
+    if interpret is None:
+        if backend not in ("tpu", "cpu"):
+            raise RuntimeError(f"no Pallas path for backend {backend!r}: "
+                               "kernels compile on tpu and interpret on cpu")
+        return backend == "cpu"
+    if interpret and backend != "cpu":
+        raise RuntimeError(f"interpret mode requested on backend {backend!r}; "
+                           "it is allowed on cpu only")
+    return interpret
 
 
 def pow2_bucket(n: int, *, floor: int = 1) -> int:
@@ -62,25 +79,32 @@ def gram_moment(A: jax.Array, b: jax.Array, *, block_d: int = 128,
     block_n = min(block_n, max(8, 1 << (n - 1).bit_length()))
     Ap = _pad_to(_pad_to(A, 0, block_n), 1, block_d)
     bp = _pad_to(b, 0, block_n)
-    interpret = _interpret_default() if interpret is None else interpret
     G, h = gram_kernel.gram_moment_pallas(
-        Ap, bp, block_d=block_d, block_n=block_n, interpret=interpret)
+        Ap, bp, block_d=block_d, block_n=block_n,
+        interpret=_interpret(interpret))
     return G[:d, :d], h[:d]
 
 
-def _feature_blocks(n: int, d: int, m_padded: int,
-                    block_d: int, block_n: int) -> tuple[int, int]:
-    """Clamp (block_d, block_n) for the fused featurize->Gram kernels.
+def _feature_blocks(n: int, d: int, m_padded: int, block_d: int,
+                    block_n: int, block_m: int = 512
+                    ) -> tuple[int, int, int]:
+    """Tiles (block_d, block_n, block_m) for the fused featurize->Gram kernels.
 
-    Same pow2 clamping as :func:`gram_moment`, then halve block_n until the
-    (block_n, m_padded) f32 T scratch fits a 4 MB VMEM budget (block_n stays
-    a multiple of 8, the fp32 sublane tile).
+    Same pow2 clamping of (block_d, block_n) as :func:`gram_moment`; block_m
+    is ``min(block_m, m_padded)`` halved until it divides ``m_padded`` (a
+    multiple of 128), so the G output tile is (block_m, block_m) whatever m
+    is. block_n then halves until the two (block_n, block_m) f32 feature-tile
+    scratches fit a 4 MB VMEM budget (block_n stays a multiple of 8, the
+    fp32 sublane tile).
     """
     block_d = min(block_d, max(128, 1 << (d - 1).bit_length()))
     block_n = min(block_n, max(8, 1 << (n - 1).bit_length()))
-    while block_n > 8 and block_n * m_padded * 4 > 4 * 1024 * 1024:
+    block_m = min(block_m, m_padded)
+    while m_padded % block_m:
+        block_m //= 2
+    while block_n > 8 and 2 * block_n * block_m * 4 > 4 * 1024 * 1024:
         block_n //= 2
-    return block_d, block_n
+    return block_d, block_n, block_m
 
 
 def sketch_gram(A: jax.Array, b: jax.Array, R: jax.Array, *,
@@ -95,14 +119,15 @@ def sketch_gram(A: jax.Array, b: jax.Array, R: jax.Array, *,
     """
     n, d = A.shape
     m = R.shape[1]
-    mp = max(128, 1 << (m - 1).bit_length())
-    block_d, block_n = _feature_blocks(n, d, mp, block_d, block_n)
+    Rp = _pad_to(R, 1, 128)
+    block_d, block_n, block_m = _feature_blocks(n, d, Rp.shape[1], block_d,
+                                                block_n)
     Ap = _pad_to(_pad_to(A, 0, block_n), 1, block_d)
     bp = _pad_to(b, 0, block_n)
-    Rp = _pad_to(_pad_to(R, 0, block_d), 1, mp)
-    interpret = _interpret_default() if interpret is None else interpret
+    Rp = _pad_to(Rp, 0, block_d)
     G, h = gram_kernel.sketch_gram_pallas(
-        Ap, bp, Rp, block_d=block_d, block_n=block_n, interpret=interpret)
+        Ap, bp, Rp, block_d=block_d, block_n=block_n, block_m=block_m,
+        interpret=_interpret(interpret))
     return G[:m, :m], h[:m]
 
 
@@ -120,16 +145,16 @@ def rff_gram(X: jax.Array, b: jax.Array, W: jax.Array, c: jax.Array, *,
     """
     n, d = X.shape
     D = W.shape[1]
-    Dp = max(128, 1 << (D - 1).bit_length())
-    block_d, block_n = _feature_blocks(n, d, Dp, block_d, block_n)
+    Wp = _pad_to(W, 1, 128)
+    block_d, block_n, block_m = _feature_blocks(n, d, Wp.shape[1], block_d,
+                                                block_n)
     Xp = _pad_to(_pad_to(X, 0, block_n), 1, block_d)
     bp = _pad_to(b, 0, block_n)
-    Wp = _pad_to(_pad_to(W, 0, block_d), 1, Dp)
-    cp = _pad_to(c, 0, Dp)
-    interpret = _interpret_default() if interpret is None else interpret
+    Wp = _pad_to(Wp, 0, block_d)
+    cp = _pad_to(c, 0, Wp.shape[1])
     G, h = gram_kernel.rff_gram_pallas(
-        Xp, bp, Wp, cp, n_valid=n, true_dim=D,
-        block_d=block_d, block_n=block_n, interpret=interpret)
+        Xp, bp, Wp, cp, n_valid=n, true_dim=D, block_d=block_d,
+        block_n=block_n, block_m=block_m, interpret=_interpret(interpret))
     return G[:D, :D], h[:D]
 
 
@@ -150,10 +175,9 @@ def gemm_nt(C: jax.Array, A: jax.Array, B: jax.Array, *, alpha: float = -1.0,
     Cp = _pad_to(_pad_to(C, 0, block_m), 1, block_n)
     Ap = _pad_to(_pad_to(A, 0, block_m), 1, 128)
     Bp = _pad_to(_pad_to(B, 0, block_n), 1, 128)
-    interpret = _interpret_default() if interpret is None else interpret
     out = gram_kernel.gemm_nt_pallas(Cp, Ap, Bp, alpha=alpha,
                                      block_m=block_m, block_n=block_n,
-                                     interpret=interpret)
+                                     interpret=_interpret(interpret))
     return out[:m, :n]
 
 
@@ -226,7 +250,7 @@ def swa_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     masked (never-attended, never-attending) positions and sliced back.
     """
     B, S, H, hd = q.shape
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _interpret(interpret)
     block_q = min(block_q, S)
     block_k = min(block_k, S)
     pad = (-S) % max(block_q, block_k)

@@ -18,6 +18,11 @@ Usage (a 3-client federation against ``serve.py --mode fusion --listen``)::
     python src/repro/launch/client.py --connect 127.0.0.1:7777 \
         --tenant ridge --seed 0 --num-clients 3 --client-index 0 \
         --samples 128 --dim 32 --offer f64,f32 --solve 0.1
+
+Clients stand for other machines. On a host with a TPU, the chip belongs to
+the one server process, so client processes there run with
+``JAX_PLATFORMS=cpu``: a second process that reached for the chip would fail
+or hang.
 """
 from __future__ import annotations
 
@@ -244,4 +249,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     main()

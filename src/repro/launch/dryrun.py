@@ -1,12 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh).
 
-The two lines above MUST precede any jax-importing statement: jax locks the
-device count at first init, and the dry-run needs 512 host-platform
-placeholder devices to build the production meshes. (Smoke tests and
-benchmarks run in separate processes and see 1 device.)
+``main`` asks for 512 host-platform placeholder devices (to build the
+production meshes) before anything initializes jax, which locks the device
+count at first init. Importing this module changes no process state: tests
+import its parsers and keep the device count they started with.
 
 Per combination this produces up to three artifacts:
 
@@ -264,6 +261,7 @@ def all_combos():
 
 
 def main() -> None:
+    jax.config.update("jax_num_cpu_devices", 512)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

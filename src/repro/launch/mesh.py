@@ -6,21 +6,17 @@ as (pod=2, data=16, model=16) — the "pod" axis carries only data parallelism
 statistic reductions.
 
 Defined as functions so importing this module never touches jax device state
-(jax locks the device count on first init; dryrun.py must set XLA_FLAGS
-before anything initializes jax).
+(jax locks the device count on first init; dryrun.py sets it before
+anything initializes jax).
 """
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed after jax 0.4; older runtimes use implicit Auto axes.
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _axis_types(n: int) -> dict:
-    return {"axis_types": (AxisType.Auto,) * n} if AxisType is not None else {}
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -34,39 +30,33 @@ def make_host_mesh(shape=(4, 2), axes=("data", "model")):
 
     Assumes the platform actually exposes prod(shape) devices (i.e.
     ``--xla_force_host_platform_device_count`` was set before jax
-    initialized) and raises otherwise; :func:`make_cpu_mesh` is the
-    degrading variant for code that must run anywhere.
+    initialized) and raises otherwise.
     """
     return jax.make_mesh(shape, axes, **_axis_types(len(axes)))
 
 
-def make_cpu_mesh(n: int = 8, axes=("data", "model")):
-    """Mesh over up to ``n`` host-platform devices; degrades, never crashes.
+def make_device_mesh(n: int | None = None, axes=("data", "model")):
+    """2-D mesh over the first ``n`` of ``jax.devices()`` (default: all).
 
-    The host platform only exposes multiple devices when
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` is set *before*
-    jax first initializes (jax locks the device count at first init). This
-    helper validates that expectation: when fewer than ``n`` devices exist
-    it warns with the exact flag to set and builds the largest 2-D mesh that
-    fits — down to a 1x1 single-device mesh — instead of raising the way a
-    fixed-shape ``make_host_mesh`` does.
+    Raises ``ValueError`` when fewer than ``n`` devices exist: a mesh that
+    silently shrinks would run a "sharded" tenant on fewer chips than asked
+    for (down to one) and report nothing. On the CPU platform the device
+    count is fixed by ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+    set *before* jax initializes.
 
     The ``n`` devices are arranged as the most-square (rows, cols)
     factorization with rows >= cols, so the fusion server's 2-D
     block-sharding gets balanced tiles.
     """
-    import warnings
-
-    avail = jax.device_count()
-    if avail < n:
-        warnings.warn(
-            f"make_cpu_mesh: requested {n} devices but the platform has "
-            f"{avail}; set XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{n} before jax initializes to get the full mesh",
-            stacklevel=2)
-    n_eff = min(n, avail)
-    cols = max(c for c in range(1, int(n_eff ** 0.5) + 1) if n_eff % c == 0)
-    return jax.make_mesh((n_eff // cols, cols), axes, **_axis_types(len(axes)))
+    devices = jax.devices()
+    n = len(devices) if n is None else n
+    if not 1 <= n <= len(devices):
+        raise ValueError(
+            f"mesh of {n} devices requested; the {jax.default_backend()} "
+            f"platform has {len(devices)}")
+    cols = max(c for c in range(1, int(n ** 0.5) + 1) if n % c == 0)
+    return jax.make_mesh((n // cols, cols), axes, devices=devices[:n],
+                         **_axis_types(len(axes)))
 
 
 def client_axes(mesh) -> tuple[str, ...]:

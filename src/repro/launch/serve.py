@@ -48,44 +48,6 @@ from repro import configs
 from repro.models import model
 
 
-def enable_compilation_cache(path: str, *,
-                             min_compile_time_s: float = 0.0,
-                             min_entry_size_bytes: int = 0) -> bool:
-    """Point jax's persistent compilation cache at ``path``.
-
-    Server restarts otherwise pay every jit compile again — on the serving
-    path that lands squarely in the first requests' tail latencies. With the
-    cache on, a restarted server replays compiled executables from disk and
-    the cold-start tail collapses to dispatch cost. The threshold configs
-    are set to "cache everything" by default because fusion-serving programs
-    are small and numerous (per-(d, dtype, bucket) specializations).
-
-    Returns True when the cache was enabled; False (with a warning) on jax
-    versions exposing none of the expected config knobs — callers treat the
-    cache as best-effort, never a hard dependency.
-    """
-    enabled = False
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        enabled = True
-    except AttributeError:                      # pragma: no cover - old jax
-        import warnings
-
-        warnings.warn("jax has no jax_compilation_cache_dir config; "
-                      "persistent compilation cache disabled", stacklevel=2)
-        return False
-    # Optional tuning knobs — present on current jax, harmless to skip.
-    for key, val in (
-            ("jax_persistent_cache_min_compile_time_secs", min_compile_time_s),
-            ("jax_persistent_cache_min_entry_size_bytes",
-             min_entry_size_bytes)):
-        try:
-            jax.config.update(key, val)
-        except AttributeError:                  # pragma: no cover - old jax
-            pass
-    return enabled
-
-
 def serve(arch: str, *, reduced: bool = True, batch: int = 4,
           prompt_len: int = 32, gen_tokens: int = 32, seed: int = 0,
           greedy: bool = True) -> dict:
@@ -640,12 +602,6 @@ def main() -> None:
                          "path — concurrent queries landing within it "
                          "coalesce into one cross-tenant stacked sweep; a "
                          "lone request never waits")
-    ap.add_argument("--compilation-cache", type=str, default=None,
-                    metavar="PATH",
-                    help="persistent jax compilation cache directory: a "
-                         "restarted server replays compiled executables "
-                         "from disk instead of re-paying every jit compile "
-                         "in its first requests' tail latencies")
     ap.add_argument("--journal-dir", type=str, default=None, metavar="DIR",
                     help="with --listen: write-ahead journal + snapshot "
                          "directory; every admitted frame is journaled "
@@ -695,8 +651,6 @@ def main() -> None:
     ap.add_argument("--chaos-seed", type=int, default=0,
                     help="seed of the chaos proxy's fault schedule")
     args = ap.parse_args()
-    if args.compilation_cache:
-        enable_compilation_cache(args.compilation_cache)
     if args.mode == "relay" and args.upstream is None:
         ap.error("--mode relay requires --upstream HOST:PORT")
     if args.mode == "relay" or (args.mode == "fusion"
@@ -798,4 +752,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     main()

@@ -30,7 +30,8 @@ from typing import Any, Protocol, Sequence, runtime_checkable
 import jax
 import jax.numpy as jnp
 
-from repro.core.sufficient_stats import SuffStats, zeros_like_stats
+from repro.core.sufficient_stats import (MATMUL_PRECISION, SuffStats,
+                                         zeros_like_stats)
 from repro.kernels.ops import pow2_bucket
 from repro.server.cholesky import chol_update, chol_update_blocked
 
@@ -136,8 +137,9 @@ def _spectral_solve(lam, Q, h, sigmas):
     Corollary-1 structure: G + sigma I shares G's eigenbasis, so after ONE
     eigh every sigma costs only matmuls — O(d^2) per sigma, no factorization.
     """
-    qh = Q.T @ h
-    return (qh[None] / (lam[None] + sigmas[:, None])) @ Q.T
+    qh = jnp.matmul(Q.T, h, precision=MATMUL_PRECISION)
+    return jnp.matmul(qh[None] / (lam[None] + sigmas[:, None]), Q.T,
+                      precision=MATMUL_PRECISION)
 
 
 class DenseBackend:

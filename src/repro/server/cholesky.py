@@ -38,6 +38,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core.sufficient_stats import MATMUL_PRECISION
+
 
 @partial(jax.jit, static_argnames=("sign",))
 def chol_rank1(L: jax.Array, x: jax.Array, *, sign: float = 1.0) -> jax.Array:
@@ -162,13 +164,13 @@ def chol_update_blocked(L: jax.Array, U: jax.Array, *, sign: float = 1.0,
 
                 Zn = kernel_ops.gemm_nt(jnp.zeros_like(Z), Z, T.T, alpha=1.0)
             else:
-                Zn = Z @ T
+                Zn = jnp.matmul(Z, T, precision=MATMUL_PRECISION)
             L = L.at[c1:, c0:c1].set(Zn[:, :bw])
             X = X.at[:, c1:].set(Zn[:, bw:].T)
     return L
 
 
-def psd_update_vectors(G: jax.Array, *, tol: float = 1e-7) -> jax.Array:
+def psd_update_vectors(G: jax.Array) -> jax.Array:
     """Rows U (r, d) with ``U^T U ~= G`` for PSD G, r = numerical rank.
 
     One eigendecomposition turns an arbitrary PSD delta (e.g. a departing
@@ -176,16 +178,22 @@ def psd_update_vectors(G: jax.Array, *, tol: float = 1e-7) -> jax.Array:
     explicit update vectors. The O(d^3) cost is paid once per delta and
     amortized across every cached per-sigma factor it is applied to.
 
+    The rank cutoff is ``eps * d * lambda_max`` for G's dtype: the
+    eigensolver's round-off on a d x d matrix reaches that scale, so any
+    fixed relative tolerance is either below the f32 noise floor (noise
+    vectors join the downdate) or far above the f64 one.
+
     Host-side on purpose: r must be concrete so downstream scans have a
     static shape.
     """
+    d = G.shape[0]
     evals, evecs = jnp.linalg.eigh(G)
     evals = jax.device_get(evals)
-    cutoff = tol * max(float(evals[-1]), 1.0)
-    keep = evals > cutoff
-    r = int(keep.sum())
+    eps = float(jnp.finfo(G.dtype).eps)
+    cutoff = eps * d * max(float(evals[-1]), float(jnp.finfo(G.dtype).tiny))
+    r = int((evals > cutoff).sum())
     if r == 0:
-        return jnp.zeros((0, G.shape[0]), G.dtype)
+        return jnp.zeros((0, d), G.dtype)
     vecs = evecs[:, -r:]
     vals = jnp.clip(jnp.asarray(evals[-r:]), 0.0, None)
     return (vecs * jnp.sqrt(vals)).T

@@ -55,10 +55,10 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.sufficient_stats import SuffStats, compute_stats
+from repro.core.sufficient_stats import (MATMUL_PRECISION, SuffStats,
+                                         compute_stats)
 from repro.kernels import ops as kernel_ops
 from repro.launch.sharding import FUSION_RULES, GRAM_AXES, ShardingRules
 from repro.server.cholesky import panel_transform
@@ -262,10 +262,10 @@ class ShardedBackend:
         key = ("update", bucket, sign > 0)
         fn = self._jitted.get(key)
         if fn is None:
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 partial(self._local_update, sign=1.0 if sign > 0 else -1.0),
                 mesh=self.mesh, in_specs=(self.spec, P()),
-                out_specs=self.spec, check_rep=False))
+                out_specs=self.spec, check_vma=False))
             self._jitted[key] = fn
         U = jnp.pad(update_vectors.astype(self._dtype),
                     ((0, bucket - r), (0, self.padded - self._dim)))
@@ -321,12 +321,12 @@ class ShardedBackend:
 
         fn = self._jitted.get("fuse_dist")
         if fn is None:
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P(_spec_entry(row_axes)), P(_spec_entry(row_axes)),
                           P()),
                 out_specs=(self.spec, P(), P()),
-                check_rep=False))
+                check_vma=False))
             self._jitted["fuse_dist"] = fn
         dG, dh, dc = fn(A, b, participation)
         add = self._jitted.get("fuse_add")
@@ -358,10 +358,10 @@ class ShardedBackend:
             return ShardedFactor("cg", float(sigma))
         fn = self._jitted.get("factor")
         if fn is None:
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 self._local_chol, mesh=self.mesh,
                 in_specs=(self.spec, P()), out_specs=self.spec,
-                check_rep=False))
+                check_vma=False))
             self._jitted["factor"] = fn
         L = fn(self._G, jnp.asarray(sigma, self._dtype))
         return ShardedFactor("block_chol", float(sigma), L)
@@ -371,10 +371,10 @@ class ShardedBackend:
             return self._cg_solve(factor.sigma)
         fn = self._jitted.get("solve")
         if fn is None:
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 self._local_tri_solve, mesh=self.mesh,
                 in_specs=(self.spec, P()), out_specs=P(),
-                check_rep=False))
+                check_vma=False))
             self._jitted["solve"] = fn
         return fn(factor.L, self._h)[: self._dim]
 
@@ -483,7 +483,7 @@ class ShardedBackend:
             if self.use_pallas:
                 Zn = kernel_ops.gemm_nt(jnp.zeros_like(Z), Z, T.T, alpha=1.0)
             else:
-                Zn = Z @ T
+                Zn = jnp.matmul(Z, T, precision=MATMUL_PRECISION)
             below = (g >= c0 + bs)[:, None]
             new_strip = jnp.where(below, Zn[:, :bs], strip)
             new_strip = new_strip.at[lr0:lr0 + bs].set(
@@ -515,7 +515,7 @@ class ShardedBackend:
         """Trailing update Gl - a @ bmat^T on this shard's tile."""
         if self.use_pallas:
             return kernel_ops.gemm_nt(Gl, a, bmat, alpha=-1.0)
-        return Gl - a @ bmat.T
+        return Gl - jnp.matmul(a, bmat.T, precision=MATMUL_PRECISION)
 
     def _diag_tiles(self, Ll):
         """All nb diagonal bs x bs tiles, replicated (one psum up front)."""
@@ -556,7 +556,8 @@ class ShardedBackend:
             pk = c0 // rl
             lr0 = c0 - pk * rl
             yc = jax.lax.dynamic_slice(y, (co,), (cl,))
-            part = Ll[lr0:lr0 + bs, :] @ yc
+            part = jnp.matmul(Ll[lr0:lr0 + bs, :], yc,
+                              precision=MATMUL_PRECISION)
             s = _psum(jnp.where(ri == pk, part, 0.0), all_axes)
             yk = jax.scipy.linalg.solve_triangular(
                 diag[k], h[c0:c0 + bs] - s, lower=True)
@@ -571,7 +572,8 @@ class ShardedBackend:
             qk = c0 // cl
             lc0 = c0 - qk * cl
             xr = jax.lax.dynamic_slice(x, (ro,), (rl,))
-            part = Ll[:, lc0:lc0 + bs].T @ xr
+            part = jnp.matmul(Ll[:, lc0:lc0 + bs].T, xr,
+                              precision=MATMUL_PRECISION)
             s = _psum(jnp.where(ci == qk, part, 0.0), all_axes)
             xk = jax.scipy.linalg.solve_triangular(
                 diag[k].T, y[c0:c0 + bs] - s, lower=False)
@@ -593,9 +595,9 @@ class ShardedBackend:
                 full = _gather(rows, row_axes)            # (dp,)
                 return full + sigma * x
 
-            fn = shard_map(local_mv, mesh=self.mesh,
+            fn = jax.shard_map(local_mv, mesh=self.mesh,
                            in_specs=(self.spec, P(), P()), out_specs=P(),
-                           check_rep=False)
+                           check_vma=False)
             self._jitted["matvec"] = fn
         return fn
 
@@ -615,9 +617,9 @@ class ShardedBackend:
                                  col_axes)
                     return _gather(mine, row_axes)
 
-                fn = jax.jit(shard_map(local_diag, mesh=self.mesh,
+                fn = jax.jit(jax.shard_map(local_diag, mesh=self.mesh,
                                        in_specs=(self.spec,), out_specs=P(),
-                                       check_rep=False))
+                                       check_vma=False))
                 self._jitted["diag"] = fn
             self._diag = fn(self._G)
         return self._diag

@@ -55,7 +55,8 @@ from typing import Any, Callable, Hashable, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core.sufficient_stats import SuffStats, compute_stats, fuse_stats
+from repro.core.sufficient_stats import (MATMUL_PRECISION, SuffStats,
+                                         compute_stats, fuse_stats)
 from repro.server.backends import DenseBackend, LinalgBackend
 from repro.server.cholesky import psd_update_vectors
 
@@ -116,7 +117,7 @@ class FusionEngine:
 
     def __init__(self, dim: int, *, dtype=None,
                  backend: LinalgBackend | None = None,
-                 max_update_rank: int | None = None, rank_tol: float = 1e-7,
+                 max_update_rank: int | None = None,
                  coalesce: CoalescerPolicy | None = None):
         if backend is None:
             backend = DenseBackend(dim, dtype=dtype if dtype is not None
@@ -138,7 +139,6 @@ class FusionEngine:
         self._factors: dict[float, _CachedFactor] = {}
         self.max_update_rank = (max(1, dim // 4) if max_update_rank is None
                                 else max_update_rank)
-        self.rank_tol = rank_tol
         self.dtype = self.backend.dtype
         self.coalesce = (CoalescerPolicy(max_rank=self.max_update_rank)
                          if coalesce is None else coalesce)
@@ -455,11 +455,13 @@ class FusionEngine:
             self._factors.clear()
             return update_vectors
         if update_vectors is None:
-            # rank(G_k) <= min(rows, d); skip the eigh when it cannot pay off.
+            # rank(G_k) <= min(rows, d); skip the eigh when it cannot pay
+            # off: no cached factor has that much staleness budget left.
             bound = min(int(delta.count), self.dim)
-            if bound <= self.max_update_rank:
-                update_vectors = psd_update_vectors(delta.gram,
-                                                    tol=self.rank_tol)
+            room = self.max_update_rank - min(
+                f.stale_rank for f in self._factors.values())
+            if bound <= room:
+                update_vectors = psd_update_vectors(delta.gram)
         rank = None if update_vectors is None else int(update_vectors.shape[0])
         fresh: dict[float, _CachedFactor] = {}
         for sigma, f in self._factors.items():
@@ -622,7 +624,8 @@ class FusionEngine:
         losses = jnp.zeros((len(sigmas),), self.dtype)
         for k, cid in enumerate(ids):
             A_k, b_k = client_data[cid]
-            resid = A_k @ W[k].T - b_k[:, None]     # (n_k, S)
+            resid = (jnp.matmul(A_k, W[k].T, precision=MATMUL_PRECISION)
+                     - b_k[:, None])                  # (n_k, S)
             losses = losses + jnp.mean(resid**2, axis=0)
         best = int(jnp.argmin(losses))
         return sigmas[best], losses
@@ -631,7 +634,7 @@ class FusionEngine:
 
     def predict(self, A: jax.Array, sigma: float) -> jax.Array:
         """Hot path: ridge predictions for query rows at one sigma."""
-        return A @ self.solve(sigma)
+        return jnp.matmul(A, self.solve(sigma), precision=MATMUL_PRECISION)
 
     def inference(self, sigma: float, *, level: float = 0.95,
                   queries: jax.Array | None = None) -> dict | None:
@@ -661,4 +664,5 @@ class FusionEngine:
 
     def predict_batch(self, A: jax.Array, sigmas: Sequence[float]) -> jax.Array:
         """(S, n) predictions — n query rows against S regularizations."""
-        return self.solve_batch(sigmas) @ A.T
+        return jnp.matmul(self.solve_batch(sigmas), A.T,
+                          precision=MATMUL_PRECISION)

@@ -34,11 +34,13 @@ next to privatized (G, h) would leak (core.privacy).
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.sufficient_stats import SuffStats
+from repro.core.sufficient_stats import MATMUL_PRECISION, SuffStats
 
 
 @jax.jit
@@ -53,12 +55,13 @@ def _inference_kernel(L, G, h, w, yty, n, sigma):
     d = G.shape[0]
     eye = jnp.eye(d, dtype=G.dtype)
     Linv = jax.scipy.linalg.solve_triangular(L, eye, lower=True)
-    M = Linv.T @ Linv
+    mm = partial(jnp.matmul, precision=MATMUL_PRECISION)
+    M = mm(Linv.T, Linv)
     dof = d - sigma * jnp.trace(M)
-    rss = yty - 2.0 * (h @ w) + w @ (G @ w)
+    rss = yty - 2.0 * mm(h, w) + mm(w, mm(G, w))
     denom = n - dof
     sigma2 = rss / denom
-    cov = sigma2 * (M @ (G @ M))
+    cov = sigma2 * mm(M, mm(G, M))
     stderr = jnp.sqrt(jnp.clip(jnp.diag(cov), 0.0))
     return rss, dof, denom, sigma2, cov, stderr
 
@@ -70,8 +73,10 @@ def _pi_kernel(X, w, cov, sigma2):
     Var(y* - ŷ*) = σ̂² + xᵀ Cov(ŵ) x: irreducible noise plus estimation
     variance propagated through the query point.
     """
-    mean = X @ w
-    var = sigma2 + jnp.einsum("ni,ni->n", X @ cov, X)
+    mean = jnp.matmul(X, w, precision=MATMUL_PRECISION)
+    var = sigma2 + jnp.einsum("ni,ni->n",
+                              jnp.matmul(X, cov, precision=MATMUL_PRECISION),
+                              X, precision=MATMUL_PRECISION)
     return mean, jnp.sqrt(jnp.clip(var, 0.0))
 
 

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from repro.core.features import FeatureMap
 from repro.core.privacy import psd_repair
-from repro.core.sufficient_stats import SuffStats
+from repro.core.sufficient_stats import MATMUL_PRECISION, SuffStats
 from repro.server.backends import solve_snapshot
 from repro.server.engine import CoalescerPolicy, FusionEngine
 from repro.server.select import prefer_sharded
@@ -151,7 +151,7 @@ class Tenant:
 class EnginePool:
     """Named multi-tenant registry of :class:`FusionEngine` servers."""
 
-    def __init__(self, *, mesh=None, mesh_devices: int = 8,
+    def __init__(self, *, mesh=None, mesh_devices: int | None = None,
                  threshold: float | None = None, table=None,
                  max_warm: int | None = None,
                  max_tenants: int | None = None,
@@ -165,8 +165,10 @@ class EnginePool:
                  tier: str = "root"):
         """Args:
           mesh: mesh shared by every sharded tenant; built lazily
-            (``launch.mesh.make_cpu_mesh(mesh_devices)``) when omitted and a
-            tenant actually places sharded.
+            (``launch.mesh.make_device_mesh(mesh_devices)``: the first
+            ``mesh_devices`` of ``jax.devices()``, default all of them,
+            raising if fewer exist) when omitted and a tenant actually
+            places sharded.
           threshold / table: forwarded to ``server.select`` for ``"auto"``
             placement (explicit threshold beats the measured crossover).
           max_warm: LRU bound on tenants with resident factor caches
@@ -280,7 +282,7 @@ class EnginePool:
             if self._mesh is None:
                 from repro.launch import mesh as mesh_lib
 
-                self._mesh = mesh_lib.make_cpu_mesh(self._mesh_devices)
+                self._mesh = mesh_lib.make_device_mesh(self._mesh_devices)
                 self.meshes_built += 1
             return self._mesh
 
@@ -1219,7 +1221,8 @@ class EnginePool:
 
     def predict(self, name: str, A: jax.Array, sigma: float) -> jax.Array:
         """Hot-path predictions; rides the lock-snapshot ``solve``."""
-        return A @ self.solve(name, sigma)
+        return jnp.matmul(A, self.solve(name, sigma),
+                          precision=MATMUL_PRECISION)
 
     def predict_batch(self, name: str, A: jax.Array,
                       sigmas: Sequence[float]) -> jax.Array:

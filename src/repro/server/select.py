@@ -8,9 +8,11 @@ beat the dense one on the bench host. This module turns that record into a
 picker:
 
   * ``backend_threshold()`` — the d at or above which the sharded backend
-    wins. Falls back to +inf (dense everywhere) when the table is missing
-    or reports a null crossover — the honest reading of a single-host CPU
-    measurement, where psums buy no bandwidth.
+    wins. Falls back to +inf (dense everywhere) when the table is missing,
+    reports a null crossover (the honest reading of a single-host CPU
+    measurement, where psums buy no bandwidth), or was measured on another
+    jax backend than the one running (a CPU crossover says nothing about a
+    TPU's).
   * ``auto_backend(dim, mesh)`` — a ready backend instance for the engine;
     ``FusionEngine.from_clients(..., backend="auto", mesh=...)`` and
     ``fed.run_one_shot(..., backend="auto", mesh=...)`` route through it.
@@ -24,6 +26,7 @@ import json
 import math
 import pathlib
 
+import jax
 import jax.numpy as jnp
 
 _TABLE = (pathlib.Path(__file__).resolve().parents[3]
@@ -35,15 +38,19 @@ def backend_threshold(threshold: float | None = None,
     """Dimension at/above which the sharded backend is preferred.
 
     Resolution order: explicit ``threshold`` -> ``crossover_d`` from the
-    measured table -> +inf (dense wins everywhere measured).
+    measured table, when its ``host.jax_backend`` is the running backend ->
+    +inf (dense wins everywhere measured).
     """
     if threshold is not None:
         return float(threshold)
     path = pathlib.Path(table) if table is not None else _TABLE
     try:
-        crossover = json.loads(path.read_text()).get("crossover_d")
+        record = json.loads(path.read_text())
     except (OSError, ValueError):
-        crossover = None
+        return math.inf
+    if record.get("host", {}).get("jax_backend") != jax.default_backend():
+        return math.inf
+    crossover = record.get("crossover_d")
     return float(crossover) if crossover is not None else math.inf
 
 

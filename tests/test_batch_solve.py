@@ -7,9 +7,8 @@ scans the SAME jitted cho_solve program the lone path runs — see
 ``server/batch.py``). The interpreter-style property test interleaves
 ``solve_many`` with ingest / drop / restore / flush / async deltas across
 mixed dense + sharded placements and asserts the bitwise equality after
-every op; a hypothesis variant rides the ``_hypo`` shim and a seeded
-variant keeps coverage unconditional, same split as
-``test_pool_properties``.
+every op; a hypothesis variant and a seeded variant drive it, same split
+as ``test_pool_properties``.
 
 Also here: pow2 sigma-grid bucketing (padded grids must not perturb real
 lanes), the ``SolveBatcher`` micro-batching window (lone requests, bursts,
@@ -24,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypo import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 from repro import core
 from repro.fed import transport
 from repro.kernels.ops import pow2_bucket

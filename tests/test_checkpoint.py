@@ -6,8 +6,6 @@ would corrupt every crash recovery downstream. Pinned here: exact roundtrips
 across the dtypes the wire actually negotiates (f64/f32/bf16), step
 discovery with gaps, and restore-onto-template casting/resharding.
 """
-import warnings
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -127,9 +125,7 @@ class TestShardedRestore:
         """Save a replicated tree, restore onto a mesh-sharded template: the
         restored leaves carry the template's sharding (this is exactly what
         the pool's snapshot restore does for sharded-placement tenants)."""
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")     # <8 host devices degrades
-            mesh = mesh_lib.make_cpu_mesh(8)
+        mesh = mesh_lib.make_device_mesh()
         sharding = NamedSharding(mesh, P("data", "model"))
         rng = np.random.default_rng(3)
         G = rng.standard_normal((8, 8)).astype(np.float32)
@@ -151,9 +147,7 @@ class TestShardedRestore:
     def test_sharded_save_gathers_to_host(self, tmp_path):
         """Saving a sharded tree works (leaves gather to host) and restores
         onto a plain template as ordinary replicated arrays."""
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            mesh = mesh_lib.make_cpu_mesh(8)
+        mesh = mesh_lib.make_device_mesh()
         x = jax.device_put(jnp.arange(16, dtype=jnp.float32).reshape(4, 4),
                            NamedSharding(mesh, P("data", "model")))
         checkpoint.save_pytree({"x": x}, tmp_path, step=0)

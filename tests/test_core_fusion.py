@@ -4,7 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypo import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 from repro import core
 
 

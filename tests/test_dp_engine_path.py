@@ -88,7 +88,7 @@ class TestNoisyPayloadsBitExact:
         """Alg 2 noise-before-psum on-mesh, then served through an engine."""
         key = jax.random.PRNGKey(77)
         A, b = _client_rows(42, n=32)
-        mesh = mesh_lib.make_cpu_mesh(1)
+        mesh = mesh_lib.make_device_mesh(1)
         noise_fn = privacy.make_dp_noise_fn(key, EPS, DELTA, D)
         noisy = distributed_stats(A, b, mesh, client_axes=("data",),
                                   noise_fn=noise_fn)

@@ -9,16 +9,14 @@ any queued async deltas, so the coalescer must be exactly transparent to
 reads). This is the Thm 1 / Thm 8 / §VI-C algebra under adversarial
 interleaving — including the incremental (blocked) up/downdate path on both
 backends and flushes that batch several queued deltas into one mutation.
-
-Runs through the ``_hypo`` shim, so environments without hypothesis skip
-these and keep the rest of the module.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypo import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 from repro import core
 from repro.core import fusion
 from repro.launch import mesh as mesh_lib
@@ -44,13 +42,9 @@ def _make_engine(backend_kind: str) -> FusionEngine:
     # only drain at the solve — both flush paths get exercised.
     policy = CoalescerPolicy(max_rank=7)
     if backend_kind == "sharded":
-        # Degrades to a 1x1 mesh on a single-device platform; the full-mesh
-        # equivalence lives in test_sharded_backend's 8-device child.
-        import warnings
-
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            mesh = mesh_lib.make_cpu_mesh(8)
+        # Every device the platform has (one, in-process on the CPU); the
+        # full-mesh equivalence lives in test_sharded_backend's 8-device child.
+        mesh = mesh_lib.make_device_mesh()
         return FusionEngine(D, backend=ShardedBackend(D, mesh, block_size=8),
                             max_update_rank=100, coalesce=policy)
     return FusionEngine(D, max_update_rank=100, coalesce=policy)

@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypo import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 from repro import core
 from repro.core import fusion
 from repro.server import FusionEngine, chol_rank1, chol_update, psd_update_vectors

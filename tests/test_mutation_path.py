@@ -8,6 +8,8 @@ one-mutation-per-flush semantics, the fuse_stats chunked tree reduction's
 allocation bound, the tail-only streaming pad, and the measured comm
 ledger's agreement with the Theorem 4 formula.
 """
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -352,13 +354,18 @@ class TestStreamingTailPad:
         assert padded_rows and max(padded_rows) < chunk
 
 
+def _crossover_table(path, crossover, backend=None):
+    host = {"jax_backend": backend or jax.default_backend()}
+    path.write_text(json.dumps({"crossover_d": crossover, "host": host}))
+    return path
+
+
 class TestAutoBackendPicker:
     def test_threshold_resolution(self, tmp_path):
-        table = tmp_path / "crossover.json"
-        table.write_text('{"crossover_d": 384}')
+        table = _crossover_table(tmp_path / "crossover.json", 384)
         assert backend_threshold(table=table) == 384.0
         assert backend_threshold(512, table=table) == 512.0   # explicit wins
-        table.write_text('{"crossover_d": null}')
+        _crossover_table(table, None)
         assert backend_threshold(table=table) == float("inf")
         assert backend_threshold(table=tmp_path / "missing.json") \
             == float("inf")
@@ -366,20 +373,28 @@ class TestAutoBackendPicker:
     def test_auto_backend_picks_by_dim(self, tmp_path):
         from repro.launch import mesh as mesh_lib
 
-        table = tmp_path / "crossover.json"
-        table.write_text('{"crossover_d": 32}')
-        import warnings
-
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            mesh = mesh_lib.make_cpu_mesh(8)
+        table = _crossover_table(tmp_path / "crossover.json", 32)
+        mesh = mesh_lib.make_device_mesh()
         assert auto_backend(16, mesh, table=table).name == "dense"
         assert auto_backend(64, mesh, table=table).name == "sharded"
         assert auto_backend(64, None, table=table).name == "dense"
 
+    def test_table_from_another_backend_is_ignored(self, tmp_path):
+        """A crossover measured on one jax backend says nothing about
+        another: a table whose host ran elsewhere (or names no host) places
+        everything dense."""
+        from repro.launch import mesh as mesh_lib
+
+        mesh = mesh_lib.make_device_mesh()
+        other = "tpu" if jax.default_backend() != "tpu" else "cpu"
+        table = _crossover_table(tmp_path / "crossover.json", 32, other)
+        assert backend_threshold(table=table) == float("inf")
+        assert auto_backend(64, mesh, table=table).name == "dense"
+        table.write_text('{"crossover_d": 32}')
+        assert backend_threshold(table=table) == float("inf")
+
     def test_from_clients_auto(self, tmp_path):
-        table = tmp_path / "crossover.json"
-        table.write_text('{"crossover_d": null}')
+        table = _crossover_table(tmp_path / "crossover.json", None)
         s = compute_stats(jnp.ones((4, 6)), jnp.ones((4,)))
         eng = FusionEngine.from_clients({0: s}, backend="auto",
                                         threshold=backend_threshold(

@@ -14,9 +14,8 @@ solvable tenant against a cold ``core.fusion`` solve over exactly its own
 mirror — checking the untouched tenants is the isolation assertion,
 checking the touched one is Thm 1/Thm 8/§VI-C/§IV-F.
 
-The hypothesis-driven variant runs through the ``_hypo`` shim (skipped where
-hypothesis isn't installed); a seeded deterministic variant drives the same
-interpreter unconditionally so the property always has coverage.
+A hypothesis-driven variant and a seeded deterministic variant drive the
+same interpreter.
 
 Registry/admission/eviction unit tests live at the bottom.
 """
@@ -27,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypo import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 from repro import core
 from repro.core import fusion
 from repro.core.features import FeatureMap

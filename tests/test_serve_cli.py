@@ -80,20 +80,32 @@ def test_model_mode_still_requires_arch(monkeypatch, capsys):
         serve.main()
 
 
-def test_compilation_cache_flag(monkeypatch, capsys, tmp_path):
-    """--compilation-cache points jax's persistent cache at the path (and
-    the serving run still completes exactly); the helper reports whether
-    the knob exists on this jax."""
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compilation_cache_helper(monkeypatch, tmp_path, env_set):
+    """With ``JAX_COMPILATION_CACHE_DIR`` set the helper leaves JAX's config
+    untouched (JAX reads the variable itself); unset, the cache goes to the
+    fixed checkout path, the same on every run."""
+    import pathlib
+
     import jax
 
-    cache_dir = tmp_path / "jit-cache"
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    env_dir = str(tmp_path / "env-cache")
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     try:
-        out = _run_cli(monkeypatch, capsys,
-                       ["--compilation-cache", str(cache_dir)])
-        assert "[serve_fusion]" in out
-        assert jax.config.jax_compilation_cache_dir == str(cache_dir)
-        assert serve.enable_compilation_cache(str(cache_dir)) is True
+        path = compile_cache.enable_compilation_cache()
+        if env_set:
+            assert path == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            repo = pathlib.Path(__file__).resolve().parents[1]
+            assert path == str(repo / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            assert compile_cache.enable_compilation_cache() == path
     finally:
-        # tmp_path is torn down after the test; don't leave jax pointed at
-        # a vanished cache dir for the rest of the session.
-        jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_compilation_cache_dir", before)

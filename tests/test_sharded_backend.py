@@ -2,8 +2,8 @@
 
 Two layers:
 
-  * in-process tests run on whatever platform pytest got (usually 1 device;
-    ``make_cpu_mesh`` degrades) and cover the backend machinery — padding
+  * in-process tests run on a mesh over every device pytest's platform has
+    (usually 1) and cover the backend machinery — padding
     for d not divisible by the block size, CG, the Pallas tile path, engine
     integration (drop/restore/streaming, spectral fallback, cache warming).
   * the 8-device test runs in a child process with
@@ -41,7 +41,7 @@ def _problem(seed=0, n=200, d=21):
 
 @pytest.fixture(scope="module")
 def mesh():
-    return mesh_lib.make_cpu_mesh(8)
+    return mesh_lib.make_device_mesh()
 
 
 class TestShardedSolves:
@@ -281,23 +281,19 @@ class TestEngineGuards:
 
 class TestCpuMeshHelper:
     def test_degrades_to_available_devices(self):
-        with pytest.warns(UserWarning) if jax.device_count() < 64 else \
-                _nullcontext():
-            m = mesh_lib.make_cpu_mesh(64)
-        assert m.devices.size <= jax.device_count()
+        """The mesh never shrinks silently: by default it spans exactly the
+        platform's devices, and asking for more than exist raises."""
+        m = mesh_lib.make_device_mesh()
+        assert m.devices.size == jax.device_count()
         assert m.axis_names == ("data", "model")
+        with pytest.raises(ValueError, match="requested"):
+            mesh_lib.make_device_mesh(jax.device_count() + 1)
 
     def test_near_square_factorization(self):
         n = jax.device_count()
-        m = mesh_lib.make_cpu_mesh(n)
+        m = mesh_lib.make_device_mesh(n)
         r, c = m.devices.shape
         assert r * c == n and r >= c
-
-
-def _nullcontext():
-    import contextlib
-
-    return contextlib.nullcontext()
 
 
 # ---------------------------------------------------------------------------
@@ -313,7 +309,7 @@ from repro.launch import mesh as mesh_lib
 from repro.server import FusionEngine, ShardedBackend
 
 assert jax.device_count() == 8, jax.device_count()
-mesh = mesh_lib.make_cpu_mesh(8)
+mesh = mesh_lib.make_device_mesh(8)
 assert dict(mesh.shape) == {"data": 4, "model": 2}
 
 d = 100  # pads to 128 with bs=8 on a (4,2) mesh: d does NOT divide the tiling

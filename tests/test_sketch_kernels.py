@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypo import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 from repro.kernels import gram, ops, ref
 
 
@@ -161,11 +162,20 @@ class TestRFFGramKernel:
 
 class TestFeatureBlockClamping:
     def test_vmem_budget_halves_block_n(self):
-        bd, bn = ops._feature_blocks(4096, 256, 4096, 128, 512)
-        assert bn * 4096 * 4 <= 4 * 1024 * 1024
+        # The G output and the feature scratches are (block_m, block_m) and
+        # (block_n, block_m) tiles, so VMEM no longer grows with m: at
+        # m = 4096 block_n keeps its full size within the 4 MB budget.
+        bd, bn, bm = ops._feature_blocks(4096, 256, 4096, 128, 512)
+        assert 2 * bn * bm * 4 <= 4 * 1024 * 1024
+        assert bm < 4096 and 4096 % bm == 0
+        assert bn == 512 and bd == 128
+        bd, bn, bm = ops._feature_blocks(4096, 256, 4096, 128, 4096)
+        assert 2 * bn * bm * 4 <= 4 * 1024 * 1024
         assert bn % 8 == 0 and bn >= 8
-        assert bd == 128
 
     def test_small_shapes_clamp_to_pow2(self):
-        bd, bn = ops._feature_blocks(100, 48, 128, 128, 512)
-        assert bd == 128 and bn == 128
+        bd, bn, bm = ops._feature_blocks(100, 48, 128, 128, 512)
+        assert bd == 128 and bn == 128 and bm == 128
+        # the G tile halves from block_m until it divides the padded m
+        assert ops._feature_blocks(100, 48, 384, 128, 512)[2] == 384
+        assert ops._feature_blocks(100, 48, 640, 128, 512)[2] == 128

@@ -92,10 +92,7 @@ class TestShardingRules:
     def _mesh(self, multi=False):
         shape = (2, 16, 16) if multi else (16, 16)
         axes = ("pod", "data", "model") if multi else ("data", "model")
-        try:
-            return AbstractMesh(shape, axes)
-        except TypeError:  # jax<=0.4 signature: tuple of (name, size) pairs
-            return AbstractMesh(tuple(zip(axes, shape)))
+        return AbstractMesh(shape, axes)
 
     def test_param_2d_sharding(self):
         spec = DEFAULT_RULES.resolve(P("embed", "ff"), (8192, 29568), self._mesh())

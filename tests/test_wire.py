@@ -25,7 +25,8 @@ import zlib
 import numpy as np
 import pytest
 
-from _hypo import hypothesis, st
+import hypothesis
+import hypothesis.strategies as st
 from repro.fed import wire
 
 FIXDIR = pathlib.Path(__file__).resolve().parent / "fixtures" / "wire"
