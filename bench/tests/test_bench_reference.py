@@ -1,0 +1,44 @@
+"""The float64 reference against numpy.linalg.solve and the published recipe."""
+import numpy as np
+import pytest
+
+from bench import reference
+
+
+def test_ridge64_solve_matches_numpy():
+    rng = np.random.default_rng(0)
+    A, b = rng.normal(size=(300, 40)), rng.normal(size=300)
+    r = reference.Ridge64(40)
+    r.add(A[:100], b[:100])
+    r.add(A[100:], b[100:])
+    for sigma in (0.01, 1.0, 100.0):
+        w = np.linalg.solve(A.T @ A + sigma * np.eye(40), A.T @ b)
+        assert np.allclose(r.solve(sigma), w, rtol=1e-12, atol=1e-12)
+        assert r.residual(w, sigma) < 1e-12
+    assert r.n == 300 and r.yty == pytest.approx(b @ b)
+
+
+def test_ridge64_add_and_remove_round_trip():
+    rng = np.random.default_rng(1)
+    A, b = rng.normal(size=(50, 8)), rng.normal(size=50)
+    r = reference.Ridge64(8)
+    r.add(A, b)
+    s = r.copy()
+    s.add(A[:10], b[:10], sign=-1)
+    assert s.n == 40 and r.n == 50
+    assert np.allclose(s.G, A[10:].T @ A[10:])
+
+
+def test_rff_recipe_matches_the_client_library():
+    """The reference regenerates the map itself; it must be the same map."""
+    from repro.core.features import FeatureMap
+
+    fm = FeatureMap("rff", seed=123, d_orig=16, m=32, lengthscale=2.0)
+    W, c = reference.rff_arrays(123, 16, 32, 2.0)
+    Wp, cp = fm.materialize()
+    assert np.array_equal(W, np.asarray(Wp, np.float64))
+    assert np.array_equal(c, np.asarray(cp, np.float64))
+    X = np.random.default_rng(2).normal(size=(5, 16))
+    feats = reference.rff_features64(X, W, c)
+    assert feats.shape == (5, 32)
+    assert np.allclose(feats, np.sqrt(2 / 32) * np.cos(X @ W + c))
