@@ -15,9 +15,11 @@ covers both transports — a *channel* with ``request(bytes) -> bytes``:
 
 Server-side state machine (:class:`WireDispatcher` -> per-connection
 ``_Session``): HELLO fixes the session's tenant and negotiates the dtype
-(``wire.negotiate``); every other frame is handed to
-``EnginePool.admit_frame``, which creates the tenant lazily, ingests
-uploads, applies Thm-8 control, and answers SOLVE with a WEIGHTS frame.
+(``wire.negotiate``); SOLVE is answered with a WEIGHTS frame through the
+``SolveBatcher`` window (or a lone ``EnginePool.solve_lifted``); every other
+frame is handed to ``EnginePool.admit_frame``, which creates the tenant
+lazily, ingests uploads and applies Thm-8 control. Spans at these
+boundaries: ``repro.obs``.
 Malformed bytes are answered with a typed-error ACK — a hostile or buggy
 client cannot take the server down, and (for TCP) a frame whose *header*
 cannot be trusted ends the connection, because stream resync is impossible.
@@ -29,6 +31,7 @@ tests can pin the server's ledger against what clients actually sent.
 """
 from __future__ import annotations
 
+import itertools
 import logging
 import random
 import socket
@@ -39,9 +42,14 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.fed import wire
+from repro.obs import span
 
 logger = logging.getLogger(__name__)
+
+_UPLOAD_FRAMES = (wire.StatsFrame, wire.ProjectedFrame, wire.RFFFrame,
+                  wire.DeltaRowsFrame)
 
 
 class TransportError(RuntimeError):
@@ -141,12 +149,26 @@ class WireDispatcher:
         self.frames_reassembled = 0
         self.bytes_in = 0
         self.bytes_out = 0
+        # Layer counters: codec seconds, SOLVE frames answered with weights
+        # and the host's wait for those to come off the device, upload
+        # frames handed to the pool.
+        self.decode_s = 0.0
+        self.encode_s = 0.0
+        self.solve_frames = 0
+        self.upload_frames = 0
+        self.fetch_s = 0.0
+        self.fetch_max_s = 0.0
+        self._req_ids = itertools.count(1)
         self._conn_error_logged = False
+        # Collector pauses are the process's; the server reports them here.
+        obs.watch_gc()
 
-    def _count(self, **deltas: int) -> None:
+    def _count(self, **deltas: float) -> None:
         with self._lock:
             for k, v in deltas.items():
                 setattr(self, k, getattr(self, k) + v)
+            if "fetch_s" in deltas:    # one fetch per frame
+                self.fetch_max_s = max(self.fetch_max_s, deltas["fetch_s"])
 
     def session(self) -> "_Session":
         return _Session(self)
@@ -163,6 +185,13 @@ class WireDispatcher:
                 "frames_reassembled": self.frames_reassembled,
                 "bytes_in": self.bytes_in,
                 "bytes_out": self.bytes_out,
+                "decode_s": self.decode_s,
+                "encode_s": self.encode_s,
+                "solve_frames": self.solve_frames,
+                "upload_frames": self.upload_frames,
+                "fetch_s": self.fetch_s,
+                "fetch_max_s": self.fetch_max_s,
+                "gc": obs.gc_summary(),
             }
         if self.solve_batcher is not None:
             out["solve_batcher"] = self.solve_batcher.summary()
@@ -187,25 +216,38 @@ class _Session:
         self._chunk_dtag = 0
         self._chunk_payload_bytes = 0
         self._chunk_wire_bytes = 0
+        # This frame's counts, added to the dispatcher's under one lock.
+        self._tally: dict[str, float] = {}
 
     def handle(self, data: bytes) -> bytes:
         """One request frame in, one reply frame out. Never raises for
         malformed input — typed rejections come back as error ACKs."""
-        d = self.dispatcher
-        d._count(frames_handled=1, bytes_in=len(data))
+        obs.set_request(next(self.dispatcher._req_ids))
+        self._tally = {"frames_handled": 1, "bytes_in": len(data)}
+        try:
+            return self._handle(data)
+        finally:
+            self.dispatcher._count(**self._tally)
+
+    def _count(self, **deltas: float) -> None:
+        t = self._tally
+        for k, v in deltas.items():
+            t[k] = t.get(k, 0) + v
+
+    def _handle(self, data: bytes) -> bytes:
         if self._chunks is not None:
             # Mid-sequence: every frame (including the flags-0 terminal one)
             # belongs to the reassembly until it completes or aborts.
             return self._handle_chunk(data)
         try:
-            frame = wire.decode_frame(data)
+            frame = self._decode(data)
         except wire.ContinuationChunk:
             return self._handle_chunk(data)
         except wire.WireError as e:
             # Decode failures are transient from the client's view: the
             # frame may have been corrupted in transit, and a clean re-send
             # of the same bytes can succeed (dedup makes the retry safe).
-            d._count(frames_rejected=1)
+            self._count(frames_rejected=1)
             return self._reply(wire.AckFrame(
                 False, f"{type(e).__name__}: {e}", retryable=True))
         return self._dispatch(frame, encoded_len=len(data), raw=data)
@@ -227,13 +269,13 @@ class _Session:
             # positional); the client re-sends the logical frame from the
             # top on a clean buffer.
             self._reset_reassembly()
-            d._count(frames_rejected=1)
+            self._count(frames_rejected=1)
             return self._reply(wire.AckFrame(
                 False, f"{type(e).__name__}: {e}", retryable=True))
         if flags & ~wire.FLAG_CONTINUED or (
                 flags and ftype not in wire.CHUNKABLE_FRAME_TYPES):
             self._reset_reassembly()
-            d._count(frames_rejected=1)
+            self._count(frames_rejected=1)
             return self._reply(wire.AckFrame(
                 False, f"invalid chunk flags {flags:#04x} "
                        f"for frame type {ftype:#04x}", retryable=True))
@@ -242,21 +284,21 @@ class _Session:
             self._chunk_ftype, self._chunk_dtag = ftype, dtag
         elif ftype != self._chunk_ftype or dtag != self._chunk_dtag:
             self._reset_reassembly()
-            d._count(frames_rejected=1)
+            self._count(frames_rejected=1)
             return self._reply(wire.AckFrame(
                 False, "chunk sequence violation: frame type/dtype changed "
                        "mid-reassembly", retryable=True))
         cap = d.max_reassembly_bytes
         if self._chunk_payload_bytes + len(payload) > cap:
             self._reset_reassembly()
-            d._count(frames_rejected=1)
+            self._count(frames_rejected=1)
             return self._reply(wire.AckFrame(
                 False, f"reassembled payload would exceed the admission "
                        f"budget ({cap} bytes)", retryable=False))
         self._chunks.append(payload)
         self._chunk_payload_bytes += len(payload)
         self._chunk_wire_bytes += len(data)
-        d._count(chunks_received=1)
+        self._count(chunks_received=1)
         if flags & wire.FLAG_CONTINUED:
             return self._reply(wire.AckFrame(
                 True, f"chunk {len(self._chunks)} buffered"))
@@ -265,13 +307,13 @@ class _Session:
         encoded_len = self._chunk_wire_bytes
         self._reset_reassembly()
         try:
-            frame = wire.decode_frame(
+            frame = self._decode(
                 raw, max_payload_bytes=wire.MAX_REASSEMBLED_BYTES)
         except wire.WireError as e:
-            d._count(frames_rejected=1)
+            self._count(frames_rejected=1)
             return self._reply(wire.AckFrame(
                 False, f"{type(e).__name__}: {e}", retryable=True))
-        d._count(frames_reassembled=1)
+        self._count(frames_reassembled=1)
         return self._dispatch(frame, encoded_len=encoded_len, raw=raw)
 
     def _dispatch(self, frame, *, encoded_len: int, raw: bytes) -> bytes:
@@ -282,7 +324,7 @@ class _Session:
                 self.dtype = wire.negotiate(
                     frame.offers, preference=d.dtype_preference)
             except wire.NegotiationError as e:
-                d._count(frames_rejected=1)
+                self._count(frames_rejected=1)
                 return self._reply(wire.AckFrame(False, str(e)))
             return self._reply(wire.Hello(self.tenant, (self.dtype,)))
         if not isinstance(frame, (wire.StatsFrame, wire.ProjectedFrame,
@@ -290,13 +332,14 @@ class _Session:
                                   wire.ControlFrame, wire.SolveFrame)):
             # Well-formed but server-bound-only frame (WEIGHTS/ACK): a typed
             # protocol rejection, not a thread-killing dispatch error.
-            d._count(frames_rejected=1)
+            self._count(frames_rejected=1)
             return self._reply(wire.AckFrame(
                 False, f"unexpected {type(frame).__name__} from client"))
+        if isinstance(frame, _UPLOAD_FRAMES):
+            self._count(upload_frames=1)
         try:
-            if (isinstance(frame, wire.SolveFrame)
-                    and d.solve_batcher is not None):
-                reply = self._batched_solve(frame)
+            if isinstance(frame, wire.SolveFrame):
+                reply = self._solve(frame)
             else:
                 reply = d.pool.admit_frame(self.tenant, frame,
                                            encoded_len=encoded_len,
@@ -305,46 +348,66 @@ class _Session:
             # session thread; the protocol contract is a typed-error ACK.
             # Internal errors (including a journal I/O failure, which raises
             # BEFORE anything was applied) are retryable by WAL ordering.
-            d._count(frames_rejected=1)
+            self._count(frames_rejected=1)
             return self._reply(wire.AckFrame(
                 False, f"internal error: {type(e).__name__}: {e}",
                 retryable=True))
         if isinstance(reply, wire.AckFrame) and not reply.ok:
-            d._count(frames_rejected=1)
+            self._count(frames_rejected=1)
         elif isinstance(reply, wire.AckFrame) and reply.duplicate:
             # A dedup hit fused nothing: counted separately so admission
             # loops ("wait for N uploads") never double-count a retry.
-            d._count(duplicates_acked=1)
-        elif isinstance(frame, (wire.StatsFrame, wire.ProjectedFrame,
-                                wire.RFFFrame, wire.DeltaRowsFrame)):
-            d._count(uploads_admitted=1)
-        out = wire.encode_frame(_bounded_ack(reply))
+            self._count(duplicates_acked=1)
+        elif isinstance(frame, _UPLOAD_FRAMES):
+            self._count(uploads_admitted=1)
+        out = self._reply(reply)
         d.pool.record_wire_reply(self.tenant, len(out))
-        d._count(bytes_out=len(out))
         return out
 
-    def _batched_solve(self, frame):
-        """SOLVE via the micro-batching window: same reply contract as
-        ``pool.admit_frame`` — a WEIGHTS frame, or a typed-error ACK for
-        protocol-level problems (the session survives either way)."""
-        import jax
-
+    def _solve(self, frame):
+        """SOLVE, via the micro-batching window where the server has one:
+        same reply contract as ``pool.admit_frame`` — a WEIGHTS frame, or a
+        typed-error ACK for protocol-level problems (the session survives
+        either way). The weights' trip off the device is timed here on both
+        paths."""
         d = self.dispatcher
         if self.tenant not in d.pool:
             return wire.AckFrame(False, f"unknown tenant {self.tenant!r}")
+        import jax
+
+        req = obs.request()
         try:
-            w = jax.device_get(d.solve_batcher.solve(self.tenant, frame.sigma))
+            if d.solve_batcher is not None:
+                w = d.solve_batcher.solve(self.tenant, frame.sigma, req=req)
+            else:
+                w = d.pool.solve_lifted(self.tenant, frame.sigma)
+            with span("session.fetch", req=req):
+                t0 = time.perf_counter()
+                w = jax.device_get(w)
+                fetch_s = time.perf_counter() - t0
         except KeyError:
             # Raced a concurrent drop_tenant between the check and the sweep.
             return wire.AckFrame(False, f"unknown tenant {self.tenant!r}")
         except ValueError as e:
             return wire.AckFrame(False, str(e))
+        self._count(solve_frames=1, fetch_s=fetch_s)
         return wire.WeightsFrame(w=w, sigma=frame.sigma,
                                  wire_dtype=wire.dtype_name(w.dtype))
 
+    def _decode(self, data: bytes, **kw):
+        t0 = time.perf_counter()
+        try:
+            with span("wire.decode", req=obs.request()):
+                return wire.decode_frame(data, **kw)
+        finally:
+            self._count(decode_s=time.perf_counter() - t0)
+
     def _reply(self, frame) -> bytes:
-        out = wire.encode_frame(_bounded_ack(frame))
-        self.dispatcher._count(bytes_out=len(out))
+        t0 = time.perf_counter()
+        with span("wire.encode", req=obs.request()):
+            out = wire.encode_frame(_bounded_ack(frame))
+        self._count(encode_s=time.perf_counter() - t0,
+                               bytes_out=len(out))
         return out
 
 

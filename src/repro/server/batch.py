@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.ops import pow2_bucket
+from repro.obs import span
 
 
 @jax.jit
@@ -103,7 +104,8 @@ def solve_stacked(entries: Sequence[tuple[jax.Array, jax.Array]]
         eye, zero = _pad_lane(d, dtype)
         Ls.extend([eye] * pad)
         hs.extend([zero] * pad)
-    ws = _stacked_solve(tuple(Ls), tuple(hs))
+    with span("batch.dispatch", lanes=T):
+        ws = _stacked_solve(tuple(Ls), tuple(hs))
     return list(ws[:T])
 
 
@@ -112,6 +114,9 @@ class _Pending:
     tenant: str
     sigma: float
     future: Future
+    req: int                      # the wire request id, for spans
+    submitted: float              # time.perf_counter() at submit
+    sweep: int = 0                # the sweep that answered it
 
 
 _STOP = object()
@@ -149,6 +154,11 @@ class SolveBatcher:
         self.lone_dispatches = 0
         self.max_batch_seen = 0
         self.fallbacks = 0
+        # submit -> start of the request's sweep, summed and longest; host
+        # seconds in pool.solve_many. Written by the batcher thread only.
+        self.queue_wait_s = 0.0
+        self.queue_wait_max_s = 0.0
+        self.sweep_s = 0.0
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -183,14 +193,24 @@ class SolveBatcher:
 
     def submit(self, tenant: str, sigma: float) -> Future:
         """Enqueue one solve; the Future resolves to the (lifted) weights."""
+        return self._submit(tenant, sigma, 0).future
+
+    def _submit(self, tenant: str, sigma: float, req: int) -> _Pending:
         if not self.alive:
             raise RuntimeError("SolveBatcher is not running; call start()")
-        f: Future = Future()
-        self._q.put(_Pending(tenant, float(sigma), f))
-        return f
+        p = _Pending(tenant, float(sigma), Future(), req,
+                     time.perf_counter())
+        self._q.put(p)
+        return p
 
-    def solve(self, tenant: str, sigma: float) -> jax.Array:
-        return self.submit(tenant, sigma).result()
+    def solve(self, tenant: str, sigma: float, *, req: int = 0) -> jax.Array:
+        """Submit and wait; ``req`` labels the spans of this request."""
+        with span("batcher.wait", req=req) as s:
+            p = self._submit(tenant, sigma, req)
+            try:
+                return p.future.result()
+            finally:
+                s.set_metadata(sweep=p.sweep)
 
     def summary(self) -> dict:
         return {
@@ -200,6 +220,9 @@ class SolveBatcher:
             "lone_dispatches": self.lone_dispatches,
             "max_batch_seen": self.max_batch_seen,
             "fallbacks": self.fallbacks,
+            "queue_wait_s": self.queue_wait_s,
+            "queue_wait_max_s": self.queue_wait_max_s,
+            "sweep_s": self.sweep_s,
         }
 
     # -- scheduler loop ------------------------------------------------------
@@ -241,21 +264,33 @@ class SolveBatcher:
             first = self._q.get()
             if first is _STOP:
                 return
-            batch, stopping = self._collect(first)
+            with span("batcher.collect"):
+                batch, stopping = self._collect(first)
             self._dispatch(batch)
             self._last_sweep_end = time.monotonic()
             if stopping:
                 return
 
     def _dispatch(self, batch: list[_Pending]) -> None:
+        start = time.perf_counter()
         self.sweeps += 1
         self.requests += len(batch)
         self.max_batch_seen = max(self.max_batch_seen, len(batch))
         if len(batch) == 1:
             self.lone_dispatches += 1
+        for p in batch:
+            p.sweep = self.sweeps
+            self.queue_wait_s += start - p.submitted
+            self.queue_wait_max_s = max(self.queue_wait_max_s,
+                                        start - p.submitted)
         try:
-            ws = self.pool.solve_many([(p.tenant, p.sigma) for p in batch],
-                                      lifted=self.lifted)
+            with span("batcher.sweep", sweep=self.sweeps, lanes=len(batch)):
+                try:
+                    ws = self.pool.solve_many(
+                        [(p.tenant, p.sigma) for p in batch],
+                        lifted=self.lifted, req_ids=[p.req for p in batch])
+                finally:
+                    self.sweep_s += time.perf_counter() - start
             for p, w in zip(batch, ws):
                 p.future.set_result(w)
         except Exception:

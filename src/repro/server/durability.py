@@ -86,11 +86,14 @@ import os
 import pathlib
 import re
 import threading
+import time
 
 import numpy as np
 
 from repro.checkpoint import load_pytree, save_pytree
 from repro.fed import wire
+from repro import obs
+from repro.obs import span
 
 SNAPSHOT_DIRNAME = "snapshots"
 _WAL_RE = re.compile(r"wal_(\d{8})\.log$")
@@ -211,6 +214,9 @@ class Journal:
         self._bound: str | None = None
         self.appends = 0
         self.markers = 0
+        # Seconds in append (its lock wait and fsync included) and in fsync.
+        self.append_s = 0.0
+        self.fsync_s = 0.0
 
     @property
     def size(self) -> int:
@@ -224,7 +230,9 @@ class Journal:
         least handed to the OS with ``fsync=False``) — only then may the
         caller apply the frame and ACK it.
         """
-        with self._lock:
+        req = obs.request()
+        t0 = time.perf_counter()
+        with span("journal.append", req=req), self._lock:
             if self._f.closed:
                 raise RuntimeError("journal is closed")
             out = b""
@@ -235,13 +243,23 @@ class Journal:
             self._f.write(out)
             self._f.flush()
             if self.fsync:
-                os.fsync(self._f.fileno())
+                with span("journal.fsync", req=req):
+                    t_sync = time.perf_counter()
+                    os.fsync(self._f.fileno())
+                    self.fsync_s += time.perf_counter() - t_sync
             self._size += len(out)
             if tenant != self._bound:
                 self.markers += 1
                 self._bound = tenant
             self.appends += 1
+            self.append_s += time.perf_counter() - t0
             return offset
+
+    def counters(self) -> dict:
+        """Appends, and the seconds spent in them and in their fsyncs."""
+        with self._lock:
+            return {"appends": self.appends, "append_s": self.append_s,
+                    "fsync_s": self.fsync_s}
 
     def switch(self, path: str | pathlib.Path) -> None:
         """Atomically (w.r.t. appends) start a fresh segment at ``path``."""

@@ -57,8 +57,10 @@ import jax.numpy as jnp
 
 from repro.core.sufficient_stats import (MATMUL_PRECISION, SuffStats,
                                          compute_stats, fuse_stats)
+from repro import obs
 from repro.server.backends import DenseBackend, LinalgBackend
 from repro.server.cholesky import psd_update_vectors
+from repro.obs import span
 
 
 @dataclasses.dataclass
@@ -149,6 +151,9 @@ class FusionEngine:
         self.incremental_updates = 0
         self.flushes = 0
         self.coalesced_deltas = 0
+        # Host seconds in ingest/ingest_rows: statistics, fusion and the
+        # dispatch of the factor updates (not their device time).
+        self.ingest_host_s = 0.0
 
     # -- construction -------------------------------------------------------
 
@@ -240,6 +245,7 @@ class FusionEngine:
             "flushes": self.flushes,
             "coalesced_deltas": self.coalesced_deltas,
             "pending_deltas": self.pending_deltas,
+            "ingest_host_s": self.ingest_host_s,
         }
 
     # -- mutation (Thm 1 / Thm 8 / §VI-C) -----------------------------------
@@ -255,6 +261,13 @@ class FusionEngine:
         without them the PSD square root is derived (or, when the delta is
         clearly high-rank, the cache is simply invalidated).
         """
+        with span("engine.ingest", req=obs.request()):
+            t0 = time.perf_counter()
+            self._ingest(stats, client_id, update_vectors)
+            self.ingest_host_s += time.perf_counter() - t0
+
+    def _ingest(self, stats: SuffStats, client_id: Hashable | None,
+                update_vectors: jax.Array | None) -> None:
         if stats.dim != self.dim:
             raise ValueError(f"stats dim {stats.dim} != engine dim {self.dim}")
         self.flush()
@@ -267,9 +280,11 @@ class FusionEngine:
     def ingest_rows(self, A: jax.Array, b: jax.Array,
                     client_id: Hashable | None = None) -> SuffStats:
         """§VI-C streaming: fold raw rows in; the rows ARE the update vectors."""
-        s = compute_stats(A, b)
-        self.ingest(s, client_id=client_id,
-                    update_vectors=A.astype(self.dtype))
+        with span("engine.ingest", req=obs.request()):
+            t0 = time.perf_counter()
+            s = compute_stats(A, b)
+            self._ingest(s, client_id, A.astype(self.dtype))
+            self.ingest_host_s += time.perf_counter() - t0
         return s
 
     # -- async ingest (coalescing queue) -------------------------------------
@@ -334,6 +349,10 @@ class FusionEngine:
         """
         if not self._pending:
             return 0
+        with span("engine.flush", req=obs.request()):
+            return self._flush()
+
+    def _flush(self) -> int:
         pending, self._pending = self._pending, []
         combined = fuse_stats([p.stats for p in pending])
         vectors = None
@@ -446,6 +465,11 @@ class FusionEngine:
 
     def _touch_factors(self, delta: SuffStats, update_vectors, sign: float):
         """Up/down-date every cached factor by a PSD delta, or evict it."""
+        with span("engine.touch_factors", req=obs.request(),
+                  factors=len(self._factors)):
+            return self._touch(delta, update_vectors, sign)
+
+    def _touch(self, delta: SuffStats, update_vectors, sign: float):
         self.stats_version += 1
         if not self._factors:
             return update_vectors

@@ -40,6 +40,7 @@ convenience — callers mixing that with concurrent pool use own the races.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -51,8 +52,10 @@ import jax.numpy as jnp
 from repro.core.features import FeatureMap
 from repro.core.privacy import psd_repair
 from repro.core.sufficient_stats import MATMUL_PRECISION, SuffStats
+from repro import obs
 from repro.server.backends import solve_snapshot
 from repro.server.engine import CoalescerPolicy, FusionEngine
+from repro.obs import span
 from repro.server.select import prefer_sharded
 
 PLACEMENTS = ("dense", "sharded", "auto")
@@ -224,6 +227,11 @@ class EnginePool:
         self.batched_sweeps = 0     # cross-tenant stacked solve sweeps run
         self.batched_solves = 0     # individual solves served by those sweeps
         self.admission_rejections = 0
+        # Waits for a tenant lock on the upload path: seconds, count, longest.
+        self._wait_lock = threading.Lock()
+        self.lock_wait_s = 0.0
+        self.lock_waits = 0
+        self.lock_wait_max_s = 0.0
         self._flusher: threading.Thread | None = None
         self._stop = threading.Event()
         # -- durability (server.durability) ---------------------------------
@@ -728,9 +736,9 @@ class EnginePool:
         This is the server half of the wire protocol: upload frames
         (STATS / PROJ / DELTA) are ingested into the tenant's engine —
         created lazily from the first frame's dimension with ``placement`` —
-        CONTROL frames drive Thm-8 drop/rejoin, and SOLVE queries return a
-        ``WeightsFrame`` (lifted through the tenant's §IV-F sketch when the
-        tenant was admitted from projected uploads). ``encoded_len`` is the
+        and CONTROL frames drive Thm-8 drop/rejoin. SOLVE is the session's
+        (``fed.transport``): it reads ``solve_lifted`` or the
+        ``SolveBatcher`` and sends the weights back. ``encoded_len`` is the
         frame's actual on-wire byte length; the pool ledger accumulates it
         for upload frames, so ``ledger()['wire_upload_bytes']`` is the sum
         of real encoded frame lengths, not a float-count formula.
@@ -744,14 +752,16 @@ class EnginePool:
         crash between the two replays the frame on restart rather than
         losing it.
 
-        Returns the reply frame (``AckFrame`` or ``WeightsFrame``).
+        Returns the reply ``AckFrame``.
         Protocol-level problems (dim mismatch, unknown tenant/client,
         conflicting sketch) come back as ``AckFrame(ok=False)`` — the
         session survives; only programming errors raise.
         """
-        reply = self._admit_frame_inner(name, frame,
-                                        encoded_len=encoded_len,
-                                        placement=placement, raw=raw)
+        with span("pool.admit", req=obs.request(),
+                  kind=type(frame).__name__):
+            reply = self._admit_frame_inner(name, frame,
+                                            encoded_len=encoded_len,
+                                            placement=placement, raw=raw)
         if self._store is not None and not self._replaying:
             # Deferred compaction: runs with no tenant lock held, so the
             # snapshot's one-lock-at-a-time capture cannot deadlock against
@@ -782,6 +792,22 @@ class EnginePool:
         return (frame.client_id, raw_b[5], len(raw_b),
                 wire.frame_crc(raw_b))
 
+    @contextlib.contextmanager
+    def _upload_lock(self, t: Tenant):
+        """``t.lock`` for an upload, its wait timed."""
+        with span("pool.lock_wait", req=obs.request()):
+            t0 = time.perf_counter()
+            t.lock.acquire()
+            waited = time.perf_counter() - t0
+        try:
+            with self._wait_lock:
+                self.lock_wait_s += waited
+                self.lock_waits += 1
+                self.lock_wait_max_s = max(self.lock_wait_max_s, waited)
+            yield
+        finally:
+            t.lock.release()
+
     @staticmethod
     def _dedup_hit(t: Tenant, key) -> bool:
         """Membership under both key generations: current 4-tuples and the
@@ -806,7 +832,7 @@ class EnginePool:
                 # One lock acquisition spans guard AND ingest (RLock — the
                 # nested _locked re-acquire is free): a concurrent upload
                 # cannot flip the tenant's space between check and fuse.
-                with t.lock:
+                with self._upload_lock(t):
                     if isinstance(frame, (wire.ProjectedFrame,
                                           wire.RFFFrame)):
                         err = self._check_feature_frame(t, frame)
@@ -841,7 +867,7 @@ class EnginePool:
                 A = jnp.asarray(frame.A)
                 b = jnp.asarray(frame.b)
                 t = self._ensure_wire_tenant(name, A.shape[1], placement)
-                with t.lock:
+                with self._upload_lock(t):
                     err = self._check_unsketched(t)
                     if err is not None:
                         return wire.AckFrame(False, err)
@@ -890,13 +916,6 @@ class EnginePool:
                     self._journal_append(name, frame, raw)
                     self._locked(name, lambda e: op(e, cid))
                 return wire.AckFrame(True, f"{frame.op} {frame.client_id!r}")
-            if isinstance(frame, wire.SolveFrame):
-                if name not in self:
-                    return wire.AckFrame(False, f"unknown tenant {name!r}")
-                w = jax.device_get(self.solve_lifted(name, frame.sigma))
-                return wire.WeightsFrame(
-                    w=w, sigma=frame.sigma,
-                    wire_dtype=wire.dtype_name(w.dtype))
         except KeyError as e:
             return wire.AckFrame(False, f"unknown client {e.args[0]!r}")
         except ValueError as e:
@@ -1131,16 +1150,19 @@ class EnginePool:
     def stats(self, name: str) -> SuffStats:
         return self._locked(name, lambda e: e.stats)
 
-    def _snapshot_factor(self, name: str, sigma: float):
+    def _snapshot_factor(self, name: str, sigma: float,
+                         req: int | None = None):
         """Under the tenant lock: drain, factor (cached), snapshot operands.
 
         Returns ``(w, None)`` when the backend declines the snapshot and the
         solve ran under the lock (e.g. sharded block factors — their solve
         is a mesh collective, not a pure function of two replicated arrays),
-        else ``(None, (L, h))`` for a lock-free solve by the caller.
+        else ``(None, (L, h))`` for a lock-free solve by the caller. ``req``
+        labels the span (default: the request this thread is handling).
         """
         t = self.tenant(name)
-        with t.lock:
+        with span("pool.snapshot", tenant=name,
+                  req=obs.request() if req is None else req), t.lock:
             age = t.engine.oldest_pending_age_s
             if age > 0.0:
                 t.max_flush_age_s = max(t.max_flush_age_s, age)
@@ -1164,7 +1186,8 @@ class EnginePool:
         return w
 
     def solve_many(self, requests: Sequence[tuple[str, float]], *,
-                   lifted: bool = False) -> list[jax.Array]:
+                   lifted: bool = False,
+                   req_ids: Sequence[int] | None = None) -> list[jax.Array]:
         """Cross-tenant batched Phase 3: many (tenant, sigma) solves, ONE
         stacked sweep per (d, dtype) bucket.
 
@@ -1178,6 +1201,7 @@ class EnginePool:
         state (pinned by tests). Backends that decline the snapshot
         (sharded) solve under their lock and skip the stack. ``lifted``
         applies each tenant's §IV-F lift (Prop 3) like ``solve_lifted``.
+        ``req_ids``, one wire request id per request, labels their spans.
 
         Buckets key on the *solve-space* dimension: a sketched/rff tenant
         snapshots its m-space factor, so it rides the SAME stacked sweep as
@@ -1188,7 +1212,8 @@ class EnginePool:
         results: list[jax.Array | None] = [None] * len(reqs)
         stacked: list[tuple[int, jax.Array, jax.Array]] = []
         for i, (name, sigma) in enumerate(reqs):
-            w, ops = self._snapshot_factor(name, sigma)
+            w, ops = self._snapshot_factor(
+                name, sigma, None if req_ids is None else req_ids[i])
             if ops is None:
                 results[i] = w
             else:
@@ -1473,6 +1498,11 @@ class EnginePool:
             "batched_sweeps": self.batched_sweeps,
             "batched_solves": self.batched_solves,
             "admission_rejections": self.admission_rejections,
+            "lock_wait_s": self.lock_wait_s,
+            "lock_waits": self.lock_waits,
+            "lock_wait_max_s": self.lock_wait_max_s,
+            "journal": (self._journal.counters() if self._journal is not None
+                        else {"appends": 0, "append_s": 0.0, "fsync_s": 0.0}),
             "resident_stat_bytes": self.resident_stat_bytes(),
             "warm_tenants": len(self.warm_tenants()),
             "journaled": self.journaled,
