@@ -165,6 +165,10 @@ class DenseBackend:
         self._stats = zeros_like_stats(dim, dtype)
         self._eigh: tuple[jax.Array, jax.Array] | None = None
         self.update_block_size = update_block_size
+        #: ``update`` calls by path: the blocked update (one Householder
+        #: reflector a panel column), the blocked downdate (hyperbolic
+        #: Givens) and the scan below ``blocked_update_min_rank``.
+        self.update_paths = {"householder": 0, "givens": 0, "scan": 0}
         self.use_pallas = (jax.default_backend() == "tpu"
                            if use_pallas is None else use_pallas)
 
@@ -235,10 +239,12 @@ class DenseBackend:
             if bucket != r:
                 update_vectors = jnp.pad(update_vectors,
                                          ((0, bucket - r), (0, 0)))
+            self.update_paths["householder" if sign > 0 else "givens"] += 1
             return chol_update_blocked(
                 factor, update_vectors, sign=sign,
                 block_size=min(self.update_block_size, self.dim),
                 use_pallas=self.use_pallas)
+        self.update_paths["scan"] += 1
         return chol_update(factor, update_vectors, sign=sign)
 
     def spectral(self, sigmas: Sequence[float]) -> jax.Array:

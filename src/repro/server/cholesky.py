@@ -13,18 +13,24 @@ Two implementations of the same algebra:
     each touching a full d-column: simple, and the pinned numerical
     reference.
   * ``chol_update_blocked`` — the production mutation path. L is processed
-    in (bd x bd) diagonal panels; within a panel the scalar recurrence runs
+    in (bd x bd) diagonal panels; within a panel the column recurrence runs
     against ALL r update vectors at once on panel-local data only, while
-    accumulating the (bd+r) x (bd+r) right-transformation T the elementary
-    steps would apply to every trailing row. The trailing panel then absorbs
-    the whole panel's worth of rotations in ONE GEMM
-    ``[L21 | X2^T] @ T^T`` — MXU-shaped, and routed through the Pallas
-    ``gemm_nt`` tile on TPU. Same r*d elementary-step chain, but each step
-    is O(bd + r) instead of O(d), and the O(r d^2) bulk rides matmuls.
+    accumulating the (bd+r) x (bd+r) orthogonal right-transformation T it
+    would apply to every trailing row. The trailing panel then absorbs the
+    whole panel in ONE GEMM ``[L21 | X2^T] @ T`` — MXU-shaped, and routed
+    through the Pallas ``gemm_nt`` tile on TPU. Each step is O(bd + r)
+    panel-local work instead of O(d), and the O(r d^2) bulk rides matmuls.
 
-Both orders perform *identical* elementary operations (the (k, j) scalars
-depend only on steps (k, j' < j) and (k' < k, j), which both orders share),
-so the blocked path is the reference up to float-associativity in the GEMM.
+The blocked path picks its panel recurrence by ``sign``. An update uses one
+Householder reflector per panel column: it zeroes all r update entries of
+that column at once, so the serial chain is d steps long. A downdate keeps
+the hyperbolic Givens chain, one rotation per (column, update vector): r*d
+steps, the same elementary operations as the scan in the same order, so
+there the blocked path is the reference up to float-associativity in the
+GEMM. An update's L matches the scan's up to round-off (the Cholesky factor
+with a positive diagonal is unique); the transformed update vectors differ
+by an r x r orthogonal factor, which later panels cannot see, since only
+``X^T X`` enters them.
 
 Numerical caveat: downdates lose accuracy as the downdated matrix approaches
 singularity. Here the result is always >= sigma I (Prop 1), but the engine
@@ -87,18 +93,80 @@ def panel_transform(L11: jax.Array, X1: jax.Array, *, sign: float = 1.0
     """Factor one diagonal panel against all r update vectors at once.
 
     Args:
-      L11: (bw, bw) lower-triangular diagonal panel of L.
+      L11: (bw, bw) lower-triangular diagonal panel of L, positive diagonal.
       X1:  (r, bw) the panel's column slice of the update vectors.
-      sign: +1 update / -1 downdate.
+      sign: +1 update / -1 downdate (static).
 
-    Returns ``(L11', T)``: the updated panel factor and the accumulated
-    (bw+r, bw+r) right-transformation, such that every trailing row obeys
+    Returns ``(L11', T)``: the updated panel factor (positive diagonal) and
+    the accumulated (bw+r, bw+r) right-transformation, such that every row
+    of the panel column obeys
 
         [L21 | X2^T] @ T  =  [L21' | X2'^T]
 
-    T is exactly the product of the elementary 2x2 column maps the scalar
-    recurrence applies — computing it costs O(bw r (bw + r)) panel-local
-    work, after which the trailing update is one GEMM.
+    and the panel's own rows obey ``[L11 | X1^T] @ T = [L11' | 0]``. For an
+    update T is orthogonal: the product of bw Householder reflectors, one
+    per column (:func:`_panel_householder`). A downdate's T is the product
+    of the hyperbolic 2x2 column maps of the Givens chain
+    (:func:`_panel_givens`). Either costs O(bw r (bw + r)) panel-local work,
+    after which the trailing update is one GEMM.
+    """
+    if sign > 0:
+        return _panel_householder(L11, X1)
+    return _panel_givens(L11, X1, sign=sign)
+
+
+def _panel_householder(L11: jax.Array, X1: jax.Array
+                       ) -> tuple[jax.Array, jax.Array]:
+    """Update panel: one reflector per column zeroes all r entries of X1[:, k].
+
+    Column k's reflector H = I - tau v v^T acts on the r+1 columns
+    {k} + update columns and maps x = [L11[k,k]; X1[:,k]] to ||x|| e_1. Its
+    first entry v_1 = x_1 - ||x|| = -||X1[:,k]||^2 / (L11[k,k] + ||x||) is
+    free of cancellation since L11[k,k] > 0. A zero X1[:,k] (a pad column
+    of the sharded layout) gives tau = 0, an exact identity; a zero row of
+    X1 (the rank bucket's pad) is 0 in every v, so its T column stays a unit
+    vector. Before step k, T's column k is still e_k, so T is kept as its bw
+    finished columns plus the (bw+r, r) block of update columns.
+    """
+    bw = L11.shape[0]
+    r = X1.shape[0]
+    idx = jnp.arange(bw)
+    eye = jnp.eye(bw + r, dtype=L11.dtype)
+
+    def col_step(k, carry):
+        L11, X1, Tpanel, TU = carry
+        Lkk = L11[k, k]
+        xk = X1[:, k]
+        sq = jnp.sum(xk * xk)
+        nonzero = sq > 0
+        alpha = jnp.where(nonzero, jnp.sqrt(Lkk * Lkk + sq), Lkk)
+        v1 = -sq / (Lkk + alpha)
+        tau = jnp.where(nonzero, 2 / (v1 * v1 + sq), 0)
+        below = idx > k
+        col = L11[:, k]
+        # Each panel row's entries [L11[i,k], X1[:,i]] dotted with v.
+        p = tau * (v1 * col + jnp.sum(xk[:, None] * X1, axis=0))
+        new_col = jnp.where(below, col - v1 * p,
+                            jnp.where(idx == k, alpha, col))
+        X1 = jnp.where(below[None, :], X1 - xk[:, None] * p[None, :], 0)
+        # The same reflector on T's columns {k} + update columns.
+        ek = (jnp.arange(bw + r) == k).astype(L11.dtype)
+        w = v1 * ek + jnp.sum(TU * xk[None, :], axis=1)
+        Tpanel = Tpanel.at[:, k].set(ek - (tau * v1) * w)
+        TU = TU - tau * w[:, None] * xk[None, :]
+        return L11.at[:, k].set(new_col), X1, Tpanel, TU
+
+    L11, _, Tpanel, TU = jax.lax.fori_loop(
+        0, bw, col_step, (L11, X1, eye[:, :bw], eye[:, bw:]))
+    return L11, jnp.concatenate([Tpanel, TU], axis=1)
+
+
+def _panel_givens(L11: jax.Array, X1: jax.Array, *, sign: float
+                  ) -> tuple[jax.Array, jax.Array]:
+    """Downdate panel: the scalar recurrence, one 2x2 map per (k, j).
+
+    T is exactly the product of the elementary column maps the scan applies,
+    in the same order: r*bw dependent steps per panel.
     """
     bw = L11.shape[0]
     r = X1.shape[0]
@@ -142,9 +210,12 @@ def chol_update_blocked(L: jax.Array, U: jax.Array, *, sign: float = 1.0,
                         use_pallas: bool = False) -> jax.Array:
     """Blocked factor of ``L L^T + sign * U^T U`` for U of shape (r, d).
 
-    The trailing-panel GEMM carries the O(r d^2) bulk; ``use_pallas`` routes
-    it through the ``kernels.ops.gemm_nt`` MXU tile (TPU; interpret-mode
-    elsewhere). ``chol_update`` is the pinned scan-of-rank-1 reference.
+    One :func:`panel_transform` per ``block_size`` panel (Householder for an
+    update, hyperbolic Givens for a downdate), then one trailing-panel GEMM
+    that carries the O(r d^2) bulk; ``use_pallas`` routes it through the
+    ``kernels.ops.gemm_nt`` MXU tile (TPU; interpret-mode elsewhere).
+    ``chol_update`` is the pinned scan-of-rank-1 reference: an update's L
+    matches it up to round-off, a downdate's up to GEMM associativity.
     """
     d = L.shape[0]
     r = U.shape[0]

@@ -256,8 +256,9 @@ class ShardedBackend:
         # Bucket the rank to the next power of two: coalescer flush ranks are
         # timing-dependent, and a shard_map retrace per distinct r would grow
         # the jit cache without bound on the hot mutation path. Zero rows are
-        # exact identities in the recurrence (x_k = 0 -> rho = L_kk, c = 1,
-        # s = 0), so rank padding costs some flops but no accuracy.
+        # exact identities in both panel recurrences (a zero row is 0 in
+        # every reflector vector and gives every rotation a zero sine), so
+        # rank padding costs some flops but no accuracy.
         bucket = kernel_ops.pow2_bucket(r)
         key = ("update", bucket, sign > 0)
         fn = self._jitted.get(key)

@@ -242,6 +242,8 @@ class FusionEngine:
             "stats_version": self.stats_version,
             "cold_factorizations": self.cold_factorizations,
             "incremental_updates": self.incremental_updates,
+            # by path, for backends that count them (DenseBackend)
+            "update_paths": dict(getattr(self.backend, "update_paths", {})),
             "flushes": self.flushes,
             "coalesced_deltas": self.coalesced_deltas,
             "pending_deltas": self.pending_deltas,

@@ -21,7 +21,7 @@ from repro.core.sufficient_stats import compute_stats, fuse_stats
 from repro.kernels import ops
 from repro.server import (CoalescerPolicy, DenseBackend, FusionEngine,
                           auto_backend, backend_threshold, chol_update,
-                          chol_update_blocked)
+                          chol_update_blocked, panel_transform)
 
 
 def _factor(d, seed=0, sigma=0.1, scale=1.0):
@@ -94,6 +94,86 @@ class TestBlockedUpdate:
                                   use_pallas=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("bw,r,zero_col,pad_rows", [
+        (8, 1, None, 0), (16, 8, None, 0), (32, 8, 5, 0), (32, 9, 0, 7),
+        (32, 64, 31, 0)])
+    def test_panel_householder_contract(self, bw, r, zero_col, pad_rows):
+        """An update's panel: T orthogonal, ``[L11 | X1^T] @ T = [L11' | 0]``
+        with L11' lower triangular and a positive diagonal; a zero column of
+        X1 and the rank bucket's zero rows leave T's rows exact identities."""
+        L, _ = _factor(bw, seed=bw + r)
+        X1 = jax.random.normal(jax.random.PRNGKey(r), (r, bw))
+        if zero_col is not None:
+            X1 = X1.at[:, zero_col].set(0.0)
+        X1 = jnp.pad(X1, ((0, pad_rows), (0, 0)))
+        L11n, T = panel_transform(L, X1, sign=1.0)
+        n = bw + r + pad_rows
+        T, L11n, X1 = np.asarray(T), np.asarray(L11n), np.asarray(X1)
+        np.testing.assert_allclose(T @ T.T, np.eye(n), atol=2e-6)
+        got = np.concatenate([np.asarray(L), X1.T], axis=1) @ T
+        np.testing.assert_allclose(got[:, :bw], L11n, atol=1e-5)
+        np.testing.assert_allclose(got[:, bw:], 0.0, atol=1e-5)
+        assert (np.diag(L11n) > 0).all()
+        np.testing.assert_array_equal(np.triu(L11n, 1), 0.0)
+        for j in range(bw + r, n):          # pad rows: untouched columns
+            np.testing.assert_array_equal(T[:, j], np.eye(n)[:, j])
+
+    def test_panel_zero_update_is_exact_identity(self):
+        L, _ = _factor(16, seed=2)
+        L11n, T = panel_transform(L, jnp.zeros((8, 16)), sign=1.0)
+        np.testing.assert_array_equal(np.asarray(L11n), np.asarray(L))
+        np.testing.assert_array_equal(np.asarray(T), np.eye(24))
+
+    @pytest.mark.parametrize("r,dtype", [
+        (1, jnp.float32), (8, jnp.float32), (9, jnp.float32),
+        (16, jnp.float32), (64, jnp.float32), (9, jnp.bfloat16)])
+    def test_householder_update_matches_scan(self, r, dtype):
+        """The update path against the scan-of-rank-1 reference, with an
+        all-zero column and all-zero rows up to the next power of two
+        (the backend's rank bucket) in the blocked call's vectors."""
+        d, bs = 80, 32                      # last panel 16 wide
+        L, _ = _factor(d, seed=r)
+        U = jax.random.normal(jax.random.PRNGKey(100 + r), (r, d))
+        U = U.at[:, 37].set(0.0)
+        X = jnp.pad(U, ((0, ops.pow2_bucket(r + 1) - r), (0, 0)))
+        ref = chol_update(L, U, sign=1.0)
+        got = chol_update_blocked(L.astype(dtype), X.astype(dtype),
+                                  sign=1.0, block_size=bs)
+        assert got.dtype == dtype
+        tol = 2e-4 if dtype == jnp.float32 else 1e-1
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(ref), rtol=tol, atol=tol)
+
+    def test_update_paths_counted(self):
+        """``FusionEngine.summary()["update_paths"]``: an 8-row ingest runs
+        the Householder update on every cached factor, a rank-12 drop the
+        Givens downdate, a 3-row ingest the scan."""
+        d, sigmas = 24, (0.1, 1.0)
+        parts = {i: compute_stats(
+            jax.random.normal(jax.random.PRNGKey(i), (12, d)),
+            jax.random.normal(jax.random.PRNGKey(50 + i), (12,)))
+            for i in range(3)}
+        eng = FusionEngine.from_clients(
+            parts, backend=DenseBackend(d, use_pallas=False),
+            max_update_rank=200)
+        for s in sigmas:
+            eng.solve(s)
+
+        def paths():
+            return eng.summary()["update_paths"]
+
+        assert paths() == {"householder": 0, "givens": 0, "scan": 0}
+        eng.ingest_rows(jax.random.normal(jax.random.PRNGKey(7), (8, d)),
+                        jax.random.normal(jax.random.PRNGKey(8), (8,)))
+        assert paths() == {"householder": len(sigmas), "givens": 0, "scan": 0}
+        eng.drop(1)                          # rank(G_1) = 12 >= 8: blocked
+        assert paths()["givens"] == len(sigmas)
+        eng.ingest_rows(jax.random.normal(jax.random.PRNGKey(9), (3, d)),
+                        jax.random.normal(jax.random.PRNGKey(10), (3,)))
+        assert paths() == {"householder": len(sigmas),
+                           "givens": len(sigmas), "scan": len(sigmas)}
+        assert eng.incremental_updates == 3 * len(sigmas)
 
     def test_rank_zero_is_identity(self):
         L, _ = _factor(8)

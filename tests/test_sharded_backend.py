@@ -99,18 +99,20 @@ class TestShardedSolves:
 
 
 class TestShardedIncrementalUpdate:
-    def test_low_rank_mutation_skips_refactorization(self, mesh):
+    @pytest.mark.parametrize("rank", [6, 16])
+    def test_low_rank_mutation_skips_refactorization(self, mesh, rank):
         """The factorization-count probe: rank <= max_update_rank mutations
         ride the distributed blocked up/downdate — NO cold refactorization,
-        and the solve still matches a cold reference."""
+        and the solve still matches a cold reference. Rank 6 buckets to 8
+        (two zero pad rows through the shared panel transform)."""
         A, b, stats = _problem(n=200, d=21)
         eng = FusionEngine.from_stats(
             stats, backend=ShardedBackend(21, mesh, block_size=8),
             max_update_rank=40)
         eng.solve(0.1)                       # warm the sharded factor
         cold0 = eng.cold_factorizations
-        eA, eb, _ = _problem(seed=5, n=6)
-        eng.ingest_rows(eA, eb)              # rank 6 <= 40 -> incremental
+        eA, eb, _ = _problem(seed=5, n=rank)
+        eng.ingest_rows(eA, eb)              # rank <= 40 -> incremental
         w = eng.solve(0.1)
         assert eng.cold_factorizations == cold0, "mutation refactorized"
         assert eng.incremental_updates == 1
