@@ -27,7 +27,9 @@ The compared numbers, each beside its limit (the configuration's
 """
 from __future__ import annotations
 
+import itertools
 import math
+import time
 
 import numpy as np
 
@@ -144,21 +146,31 @@ def screen(dep, gi: int, ti: int, qs: list, outcomes: dict, seq: list,
     residual ||(G_j + sigma I) w - h_j|| / ||h_j|| of each reply over the
     prefixes j of the tenant's deltas that read-your-writes admits, and
     that prefix. A reply for another sigma, tenant or state reads far
-    above round-off here.
+    above round-off here. Replies alike in sigma, admissible prefixes and
+    every bit of their weights read alike, so each such set is computed
+    once.
     """
     grp = dep.groups[gi]
-    W = np.stack([np.asarray(outcomes[q.idx].result, np.float64)
-                  for q in qs], axis=1)
-    sig = np.array([q.sigma for q in qs])
-    lo = np.array([max([k + 1 for k, n in enumerate(seq)
-                        if _ack_time(n, outcomes, delta_req)
-                        < outcomes[q.idx].sent], default=0) for q in qs])
-    hi = np.array([max([k + 1 for k, n in enumerate(seq)
-                        if _sent_time(n, outcomes, delta_req)
-                        < outcomes[q.idx].done], default=0) for q in qs])
+    lo_all = [max([k + 1 for k, n in enumerate(seq)
+                   if _ack_time(n, outcomes, delta_req)
+                   < outcomes[q.idx].sent], default=0) for q in qs]
+    hi_all = [max([k + 1 for k, n in enumerate(seq)
+                   if _sent_time(n, outcomes, delta_req)
+                   < outcomes[q.idx].done], default=0) for q in qs]
+    first: dict[tuple, int] = {}   # each alike set's first reply
+    col = [first.setdefault((q.sigma, lo_all[i], hi_all[i],
+                             np.asarray(outcomes[q.idx].result).tobytes()), i)
+           for i, q in enumerate(qs)]
+    uniq = sorted(first.values())
+    pos = {i: n for n, i in enumerate(uniq)}
+    W = np.stack([np.asarray(outcomes[qs[i].idx].result, np.float64)
+                  for i in uniq], axis=1)
+    sig = np.array([qs[i].sigma for i in uniq])
+    lo = np.array([lo_all[i] for i in uniq])
+    hi = np.array([hi_all[i] for i in uniq])
     GW = base.G @ W + sig * W
     h = base.h.copy()
-    best = np.full(len(qs), math.inf)
+    best = np.full(len(uniq), math.inf)
     best_j = lo.copy()
     for j in range(int(hi.max()) + 1):
         if j:
@@ -170,7 +182,8 @@ def screen(dep, gi: int, ti: int, qs: list, outcomes: dict, seq: list,
         r = np.where(np.isfinite(r), r, math.inf)
         better = (j >= lo) & (j <= hi) & (r < best)
         best[better], best_j[better] = r[better], j
-    return {q.idx: (float(best[i]), int(best_j[i])) for i, q in enumerate(qs)}
+    return {q.idx: (float(best[pos[col[i]]]), int(best_j[pos[col[i]]]))
+            for i, q in enumerate(qs)}
 
 
 def compare(dep, reqs, outcomes, seed: int, *, control: bool = False
@@ -192,16 +205,21 @@ def compare(dep, reqs, outcomes, seed: int, *, control: bool = False
         if q.kind == "solve" and q.idx in outcomes and outcomes[q.idx].ok:
             by_tenant.setdefault(q.tenant, []).append(q)
     screened: dict[int, tuple[float, int]] = {}
+    took = {"statistics": 0.0, "screen": 0.0, "solves": 0.0}
     for name, qs in by_tenant.items():
         gi, ti = where[name]
+        t0 = time.perf_counter()
         grp, X, y = _tenant_rows(dep, gi, ti)
         feat = _features(grp, ti, fmaps)
         base = reference.Ridge64(grp.spec["dim"])
         base.add(feat(X), y)
         bases[(gi, ti)] = base
+        t1 = time.perf_counter()
         screened.update(screen(dep, gi, ti, qs, outcomes,
                                order.get((gi, ti), []), delta_req, feat,
                                base))
+        took["statistics"] += t1 - t0
+        took["screen"] += time.perf_counter() - t1
     check_cfg = dep.config["check"]
     sample = sample_solves(reqs, outcomes, dep, seed,
                            int(check_cfg["sample_solves"]), streamed,
@@ -213,29 +231,46 @@ def compare(dep, reqs, outcomes, seed: int, *, control: bool = False
                np.asarray(outcomes[q.idx].result, np.float64))
               for q in sample]
 
-    # Forward errors, walking each tenant's prefixes in order.
+    # Forward errors, walking each tenant's prefixes in order; each state
+    # is solved once at all of its sampled sigmas, and the control builds
+    # each state once.
     picked.sort(key=lambda p: (p[0], p[1], p[2]))
     state = None
-    for gi, ti, j, q, w in picked:
+    solves = 0
+    for (gi, ti, j), same in itertools.groupby(picked, key=lambda p: p[:3]):
+        same = list(same)
         if state is None or state[0] != (gi, ti) or state[1] > j:
-            state = [(gi, ti), 0, bases[(gi, ti)].copy()]
+            state = [(gi, ti), 0, bases[(gi, ti)]]
         grp = dep.groups[gi]
         feat = _features(grp, ti, fmaps)
         seq = order.get((gi, ti), [])
         while state[1] < j:
+            if state[2] is bases[(gi, ti)]:
+                state[2] = state[2].copy()
             n = seq[state[1]]
             state[2].add(feat(grp.deltas[0][dep.delta_local(n)]),
                          grp.deltas[1][dep.delta_local(n)])
             state[1] += 1
-        w64 = state[2].solve(q.sigma)
-        err = reference.rel(w, w64)
-        worst = max(worst, err if math.isfinite(err) else math.inf)
+        t0 = time.perf_counter()
+        w64 = state[2].solve_many([p[3].sigma for p in same])
+        took["solves"] += time.perf_counter() - t0
+        solves += len(w64)
+        for *_, q, w in same:
+            err = reference.rel(w, w64[q.sigma])
+            worst = max(worst, err if math.isfinite(err) else math.inf)
         if control:
             from bench import control as control_lib
 
-            wc = control_lib.solve(dep, gi, ti, seq[:j], q.sigma)
-            worst_ctrl = max(worst_ctrl, reference.rel(wc, w64))
+            G, h = control_lib.stats(dep, gi, ti, seq[:j])
+            wc = {s: control_lib.solve_stats(G, h, s) for s in w64}
+            del G, h
+            for *_, q, _ in same:
+                worst_ctrl = max(worst_ctrl,
+                                 reference.rel(wc[q.sigma], w64[q.sigma]))
 
+    print(f"check: reference statistics {took['statistics']:.1f} s, "
+          f"screen {took['screen']:.1f} s, {solves} reference solves "
+          f"{took['solves']:.1f} s", flush=True)
     unanswered = sum(q.idx not in outcomes for q in reqs)
     errors = sum(1 for q in reqs if q.idx in outcomes
                  and not outcomes[q.idx].ok)

@@ -52,9 +52,15 @@ def _solve(G, h, sigma):
     return jax.scipy.linalg.cho_solve((L, True), h)
 
 
-def solve(dep, gi: int, ti: int, deltas: list[int], sigma: float
-          ) -> np.ndarray:
-    """The control's w for tenant (gi, ti) after ``deltas``, at sigma."""
+def solve_stats(G: jax.Array, h: jax.Array, sigma: float) -> np.ndarray:
+    """The control's w of statistics from :func:`stats`, at sigma."""
+    return np.asarray(jax.device_get(_solve(G, h, jnp.float32(sigma))),
+                      np.float64)
+
+
+def stats(dep, gi: int, ti: int, deltas: list[int]
+          ) -> tuple[jax.Array, jax.Array]:
+    """The control's (G, h) of tenant (gi, ti) after ``deltas``."""
     grp = dep.groups[gi]
     A, b = grp.rows
     if grp.kind == "rff":
@@ -72,8 +78,7 @@ def solve(dep, gi: int, ti: int, deltas: list[int], sigma: float
         j = dep.delta_local(n)
         dG, dh = _stats(feat(grp.deltas[0][j]), jnp.asarray(grp.deltas[1][j]))
         G, h = G + dG, h + dh
-    return np.asarray(jax.device_get(_solve(G, h, jnp.float32(sigma))),
-                      np.float64)
+    return G, h
 
 
 def main(argv=None) -> int:

@@ -5,7 +5,9 @@ gamma * u_k, per-client diagonal scales in [0.8, 1.2], noise std 0.1), drawn
 for a whole tenant group in one jitted call. Streamed delta batches are
 drawn from their site's own distribution in the same call. Client Phase 1
 (the sufficient statistics each site uploads) runs the system's client
-library, vmapped over every client of the group in one call.
+library, vmapped over every client of the group in one call; a group placed
+``sharded`` instead makes one client's statistics at a time, when its
+upload is sent, so that chip 0 never holds more than one of them.
 """
 from __future__ import annotations
 
@@ -58,6 +60,9 @@ def _dense_stats(A, b):
     return jax.vmap(jax.vmap(compute_stats))(A, b)
 
 
+_client_stats = jax.jit(compute_stats)
+
+
 @jax.jit
 def _rff_stats(X, y, W, c):
     def one_tenant(X, y, W, c):
@@ -79,6 +84,11 @@ class Group:
     stats: SuffStats | None                      # device, leading (T, K)
 
     def client_stats(self, t: int, k: int) -> SuffStats:
+        """Client k of tenant t: a slice of the group's statistics, or, for
+        a sharded group, made now from that client's rows alone."""
+        if self.placement == "sharded":
+            A, b = self.rows
+            return _client_stats(jnp.asarray(A[t, k]), jnp.asarray(b[t, k]))
         s = self.stats
         return SuffStats(s.gram[t, k], s.moment[t, k], s.count[t, k],
                          yty=s.yty[t, k])
@@ -86,6 +96,22 @@ class Group:
     @property
     def kind(self) -> str:
         return self.spec["kind"]
+
+    @property
+    def placement(self) -> str:
+        return placement_of(self.spec)
+
+
+def placement_of(group: dict) -> str:
+    """Where the group's tenants live: ``dense`` (one chip, the default) or
+    ``sharded`` (block-sharded over the cell's chips)."""
+    where = group.get("placement", "dense")
+    if where not in ("dense", "sharded"):
+        raise SystemExit(f"bench: unknown placement {where!r}")
+    if where == "sharded" and group["kind"] != "dense":
+        raise SystemExit(f"bench: a sharded group takes dense tenants, not "
+                         f"{group['kind']!r}")
+    return where
 
 
 def tenant_names(group: dict) -> list[str]:
@@ -98,6 +124,7 @@ def make_group(seed: int, gi: int, group: dict, delta_tenant, delta_site,
     """Rows, deltas and client statistics of one tenant group."""
     T, K = group["count"], group["clients"]
     d_in = group.get("d_orig", group["dim"])
+    where = placement_of(group)
     key = jax.random.fold_in(key_from_seed(seed), gi)
     A, b, dA, db = _federations(
         key, jnp.asarray(delta_tenant, jnp.int32),
@@ -115,6 +142,8 @@ def make_group(seed: int, gi: int, group: dict, delta_tenant, delta_site,
         W = jnp.stack([fm.materialize()[0] for fm in maps])
         c = jnp.stack([fm.materialize()[1] for fm in maps])
         stats = _rff_stats(A, b, W, c)
+    elif where == "sharded":
+        stats = None
     elif group["kind"] == "dense":
         stats = _dense_stats(A, b)
     else:

@@ -5,9 +5,12 @@ A ``FrameServer`` (with its ``SolveBatcher`` window) in front of an
 are admitted either over the wire (every client's STATS frame through the
 same admission and journal path that serves uploads) or, where uploads are
 not the cell's traffic, straight into the pool from statistics computed on
-the device. Set-up then warms exactly the programs the cell's traffic runs:
-each tenant's cached sigma factors, the rank-r update of a streamed delta,
-and every power-of-two stacked-sweep extent up to the mix's concurrency.
+the device. A tenant group is placed ``dense`` (one chip; the default) or
+``sharded`` (its fused statistics and factors block-sharded over a mesh of
+the cell's chips); one server takes one placement. Set-up then warms exactly
+the programs the cell's traffic runs: each tenant's cached sigma factors and
+lone solves, the rank-r update of a streamed delta, and, for dense tenants,
+every power-of-two stacked-sweep extent up to the mix's concurrency.
 """
 from __future__ import annotations
 
@@ -43,6 +46,7 @@ class Deployment:
     journal_dir: pathlib.Path | None
     journal_base_bytes: int = 0        # journal size before any delta
     journal_tail: bytes = b""          # the journal past set-up, at stop
+    placements: dict = dataclasses.field(default_factory=dict)  # at warm
 
     def delta_local(self, n: int) -> int:
         """Index of delta ``n`` among its own group's delta batches."""
@@ -102,9 +106,20 @@ def journal_size(journal_dir: pathlib.Path) -> int:
     return sum(p.stat().st_size for p in journal_dir.glob("wal_*.log"))
 
 
-def build(config: dict, seed: int, delta_reqs: list, delta_rows: int
-          ) -> Deployment:
-    """Data, server and tenants for one run; nothing warmed yet."""
+def placement(config: dict) -> str:
+    """The one placement of every tenant group of the configuration."""
+    found = {data_lib.placement_of(g) for g in config["tenants"]}
+    if len(found) != 1:
+        raise SystemExit(f"bench: tenant groups ask for placements "
+                         f"{sorted(found)}; one server takes one")
+    return found.pop()
+
+
+def build(config: dict, seed: int, delta_reqs: list, delta_rows: int,
+          chips: int = 1) -> Deployment:
+    """Data, server and tenants for one run; nothing warmed yet. Sharded
+    tenants share one mesh over the first ``chips`` devices."""
+    where_all = placement(config)
     infos = tenant_infos(config)
     # Deltas: warm-up first (site k of the first tenant that takes them),
     # then the window's, in schedule order.
@@ -134,12 +149,12 @@ def build(config: dict, seed: int, delta_reqs: list, delta_rows: int
     journal_dir = None
     if server_cfg["journal"]:
         journal_dir = pathlib.Path(tempfile.mkdtemp(prefix="bench-journal-"))
-        pool = EnginePool(journal_dir=str(journal_dir),
+        pool = EnginePool(mesh_devices=chips, journal_dir=str(journal_dir),
                           journal_fsync=bool(server_cfg["journal_fsync"]))
     else:
-        pool = EnginePool()
+        pool = EnginePool(mesh_devices=chips)
     server = transport.FrameServer(
-        pool, port=0, placement="dense",
+        pool, port=0, placement=where_all,
         solve_window_s=float(server_cfg["solve_window_s"])).start()
     return Deployment(config, pool, server, groups, infos, frames, where,
                       journal_dir)
@@ -163,6 +178,15 @@ def admit(dep: Deployment) -> None:
             with ThreadPoolExecutor(4) as ex:
                 for f in [ex.submit(upload, j) for j in jobs]:
                     f.result()
+        elif grp.spec["admit"] == "pool" and grp.placement == "sharded":
+            # One client's statistics on chip 0 at a time: each is made and
+            # ingested (the engine retains it) before the next is made.
+            for t, name in enumerate(grp.names):
+                dep.pool.create_tenant(name, dim=grp.spec["dim"],
+                                       placement="sharded")
+                for k in range(K):
+                    dep.pool.ingest(name, grp.client_stats(t, k),
+                                    client_id=f"client{k}")
         elif grp.spec["admit"] == "pool":
             for t, name in enumerate(grp.names):
                 dep.pool.create_tenant(
@@ -177,7 +201,9 @@ def admit(dep: Deployment) -> None:
 
 
 def warm(dep: Deployment, solve_sessions: int) -> None:
-    """Cached factors, the delta path, and every stacked-sweep extent."""
+    """Cached factors and lone solves, the delta path, and every
+    stacked-sweep extent of the dense tenants (sharded tenants are solved
+    alone, never stacked)."""
     pool = dep.pool
     for t in dep.tenants:
         jax.block_until_ready(pool.solve_many(
@@ -192,8 +218,9 @@ def warm(dep: Deployment, solve_sessions: int) -> None:
     buckets: dict[int, list[tuple[str, float]]] = {}
     for g, t in zip([g for g in dep.config["tenants"]
                      for _ in data_lib.tenant_names(g)], dep.tenants):
-        buckets.setdefault(g["dim"], []).extend(
-            (t.name, s) for s in t.sigmas)
+        if data_lib.placement_of(g) == "dense":
+            buckets.setdefault(g["dim"], []).extend(
+                (t.name, s) for s in t.sigmas)
     top = pow2_bucket(min(solve_sessions,
                          dep.server.dispatcher.solve_batcher.max_batch))
     for pairs in buckets.values():
@@ -211,3 +238,18 @@ def warm(dep: Deployment, solve_sessions: int) -> None:
             np.asarray(client.solve(t.sigmas[0]))
         finally:
             client.close()
+    check_placement(dep)
+
+
+def check_placement(dep: Deployment) -> dict:
+    """The pool's placements are the configuration's: a dense-only cell
+    built no mesh, and every tenant of a sharded one is on the mesh."""
+    summary = dep.pool.summary()
+    where = placement(dep.config)
+    if where == "dense" and summary["meshes_built"] != 0:
+        raise RuntimeError("bench: a dense-only cell built a mesh")
+    if summary["placements"] != {where: len(dep.tenants)}:
+        raise RuntimeError(f"bench: tenants placed {summary['placements']}, "
+                           f"the configuration asks {where}")
+    dep.placements = summary["placements"]
+    return summary

@@ -118,9 +118,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     kw = {}
     if args.small:
-        from bench.tests.small_cells import CELLS
+        from bench.tests.small_cells import CELLS, FOUR_CHIP_CELLS
 
-        kw = {"require_device": False, "overrides": CELLS[args.workload]}
+        kw = {"require_device": False,
+              "overrides": {**CELLS, **FOUR_CHIP_CELLS}[args.workload]}
     else:
         run.enable_cache()
     run._counters = _counters

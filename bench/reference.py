@@ -15,6 +15,15 @@ import numpy as np
 class Ridge64:
     """Ridge statistics and closed forms in float64 on the host."""
 
+    #: From this width on, ``solve_many`` runs conjugate gradients: below
+    #: it a float64 factorization takes under a second.
+    CG_MIN_DIM = 8192
+    #: Conjugate gradients stop once each sigma's relative residual is
+    #: below ``CG_TOL``; an answer whose true residual is not, after at
+    #: most ``CG_MAX_ITER`` passes over G, is solved by ``solve`` instead.
+    CG_TOL = 1e-13
+    CG_MAX_ITER = 500
+
     def __init__(self, d: int):
         self.G = np.zeros((d, d))
         self.h = np.zeros(d)
@@ -36,7 +45,44 @@ class Ridge64:
         return out
 
     def solve(self, sigma: float) -> np.ndarray:
-        return np.linalg.solve(self.G + sigma * np.eye(len(self.h)), self.h)
+        """(G + sigma I)^{-1} h by a float64 Cholesky factor (G + sigma I
+        is symmetric positive definite for sigma > 0)."""
+        from scipy.linalg import cho_factor, cho_solve
+
+        A = self.G + sigma * np.eye(len(self.h))
+        return cho_solve(cho_factor(A, lower=True, overwrite_a=True,
+                                    check_finite=False), self.h,
+                         check_finite=False)
+
+    def solve_many(self, sigmas) -> dict[float, np.ndarray]:
+        """``solve`` at every sigma, by one float64 factorization each or,
+        from ``CG_MIN_DIM`` on, by conjugate gradients over all of them at
+        once: one pass over G an iteration instead of d^3/3 operations a
+        sigma. A gradient answer stands only when its true residual
+        ||(G + sigma I) w - h|| / ||h|| is under 10 x ``CG_TOL``."""
+        S = np.array(sorted({float(s) for s in sigmas}))
+        h, hn = self.h, float(np.linalg.norm(self.h))
+        if len(h) < self.CG_MIN_DIM or hn == 0.0:
+            return {s: self.solve(s) for s in S}
+        X = np.zeros((len(h), len(S)))
+        R = np.repeat(h[:, None], len(S), axis=1)
+        P = R.copy()
+        rr = np.einsum("ij,ij->j", R, R)
+        for _ in range(self.CG_MAX_ITER):
+            act = np.sqrt(rr) > self.CG_TOL * hn
+            if not act.any():
+                break
+            Pa = P[:, act]
+            AP = self.G @ Pa + S[act] * Pa
+            alpha = rr[act] / np.einsum("ij,ij->j", Pa, AP)
+            X[:, act] += alpha * Pa
+            Ra = R[:, act] - alpha * AP
+            rr_new = np.einsum("ij,ij->j", Ra, Ra)
+            P[:, act] = Ra + (rr_new / rr[act]) * Pa
+            R[:, act], rr[act] = Ra, rr_new
+        true = np.linalg.norm(self.G @ X + S * X - h[:, None], axis=0) / hn
+        return {s: X[:, c].copy() if true[c] <= 10 * self.CG_TOL
+                else self.solve(s) for c, s in enumerate(S)}
 
     def residual(self, w, sigma: float) -> float:
         """||(G + sigma I) w - h|| / ||h||: how well w solves this state."""

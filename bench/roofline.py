@@ -46,3 +46,16 @@ def cho_solve_lane(d: int) -> tuple[float, float]:
     """One lane of a Cholesky solve: forward and back substitution with a
     d x d lower factor, each pass reading its triangle once."""
     return 2.0 * d * d, F32 * (d * (d + 1) + 3.0 * d)
+
+
+def sharded_tri_solve(d: int, chips: int) -> tuple[float, float]:
+    """One solve with a d x d lower factor block-sharded over ``chips``,
+    summed over the chips.
+
+    The forward and the back pass each read every entry of the lower
+    triangle once, on whichever chip holds it: the chips' tiles split the
+    triangle, so their reads add up to it. Tiles, or parts of tiles, above
+    the diagonal hold zeros that the algorithm need not read. Every chip
+    reads the replicated h and writes the replicated y and w.
+    """
+    return 2.0 * d * d, F32 * (d * (d + 1) + 3.0 * d * chips)

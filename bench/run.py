@@ -42,12 +42,14 @@ import numpy as np  # noqa: E402
 from bench import spec  # noqa: E402
 
 #: The traced part of a ``--trace 1`` window: its start and length as
-#: shares of the window, capped (traces are large and slow the host). The
-#: cap is longer than the idle gap between the silo cell's paced uploads
-#: (2.5 s), so every traced window of that cell holds factor updates.
+#: shares of the window, capped (traces are large and slow the host). In a
+#: cell with uploads the trace starts ``TRACE_LEAD_S`` before the first
+#: upload due from that start on, so that it holds the factor updates of
+#: one upload whatever the seed's phase of the paced schedule.
 TRACE_START_SHARE = 0.3
 TRACE_SHARE = 0.2
 TRACE_MAX_S = 3.0
+TRACE_LEAD_S = 0.25
 #: How long after the window closes a due reply may still come.
 REPLY_GRACE_S = 60.0
 
@@ -153,6 +155,16 @@ def _pct(xs, q) -> float | None:
     return float(np.percentile(xs, q)) if len(xs) else None
 
 
+def trace_start(reqs, seconds: float, length: float) -> float:
+    """Where in the window the trace starts, s: ``TRACE_START_SHARE`` of
+    it, or ``TRACE_LEAD_S`` before the first upload due from there whose
+    trace still ends inside the window."""
+    start = TRACE_START_SHARE * seconds
+    dues = [q.due for q in reqs if q.kind == "delta"
+            and start + TRACE_LEAD_S <= q.due <= seconds - length]
+    return min(dues) - TRACE_LEAD_S if dues else start
+
+
 def end_to_end(reqs, outcomes, t0) -> dict:
     lat = {"solve": [], "delta": []}
     for q in reqs:
@@ -194,9 +206,11 @@ def run_cell(workload: str, seed: int, seconds: float, *, trace: bool,
                       if c["kind"] == "delta"], default=1)
     marks = [("start", t_start), ("jax", time.perf_counter())]
     compiles = Compiles().__enter__()
+    chips = int(wl["chips"])
     dep = deploy.build(cfg, seed, [q for q in reqs if q.kind == "delta"],
-                       delta_rows)
+                       delta_rows, chips)
     marks.append(("data", time.perf_counter()))
+    peaks = [("data", _peak_bytes(chips))]
     warm_deltas = len(dep.delta_frames) - sum(q.kind == "delta" for q in reqs)
 
     def send(client, q):
@@ -212,8 +226,10 @@ def run_cell(workload: str, seed: int, seconds: float, *, trace: bool,
     try:
         deploy.admit(dep)
         marks.append(("admit", time.perf_counter()))
+        peaks.append(("admit", _peak_bytes(chips)))
         deploy.warm(dep, solve_sessions)
         marks.append(("warm", time.perf_counter()))
+        peaks.append(("warm", _peak_bytes(chips)))
         loop = loadgen.OpenLoop(dep.connect, groups, send)
         loop.start()
         gc.collect()
@@ -223,7 +239,9 @@ def run_cell(workload: str, seed: int, seconds: float, *, trace: bool,
         print("setup: " + ", ".join(
             f"{name} {t - marks[i][1]:.3f} s"
             for i, (name, t) in enumerate(marks[1:])) + f"; compiled "
-            f"{compiles.compiled}, loaded {compiles.loaded}", flush=True)
+            f"{compiles.compiled}, loaded {compiles.loaded}; placements "
+            f"{dep.placements}; fullest chip's peak bytes by then: "
+            + ", ".join(f"{name} {b}" for name, b in peaks), flush=True)
         compiles_before = (compiles.compiled, compiles.loaded)
         t0 = time.perf_counter() + 0.05
         driver = threading.Thread(target=loop.drive, args=(reqs, t0),
@@ -234,7 +252,7 @@ def run_cell(workload: str, seed: int, seconds: float, *, trace: bool,
             trace_dir = spec.OUT / workload / f"trace_seed{seed}"
             shutil.rmtree(trace_dir, ignore_errors=True)
             length = min(TRACE_MAX_S, TRACE_SHARE * seconds)
-            _sleep_until(t0 + TRACE_START_SHARE * seconds)
+            _sleep_until(t0 + trace_start(reqs, seconds, length))
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 0
             jax.profiler.start_trace(str(trace_dir),
@@ -249,8 +267,9 @@ def run_cell(workload: str, seed: int, seconds: float, *, trace: bool,
                            compiles.loaded - compiles_before[1])
         loop.wait_idle(REPLY_GRACE_S)
         counters1 = _counters(dep)
-        stats = jax.devices()[0].memory_stats() or {}
-        device["memory_peak_bytes"] = stats.get("peak_bytes_in_use")
+        device["memory_peak_bytes"] = _peak_bytes(chips)
+        print(f"peak bytes in use per chip: {_chip_peaks(chips)}",
+              flush=True)
         loop.close()
         outcomes = dict(loop.outcomes)
         dep.stop()
@@ -305,6 +324,21 @@ def run_cell(workload: str, seed: int, seconds: float, *, trace: bool,
         result["breakdown"] = reduced.breakdown()
     result["checks"] = checks
     return result, run
+
+
+def _chip_peaks(chips: int) -> list:
+    """Each of the cell's chips' peak bytes in use (None where the backend
+    keeps no count)."""
+    import jax
+
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in jax.devices()[:chips]]
+
+
+def _peak_bytes(chips: int) -> int | None:
+    """The peak bytes in use of the cell's fullest chip."""
+    return max((b for b in _chip_peaks(chips) if b is not None),
+               default=None)
 
 
 def _sleep_until(t: float) -> None:

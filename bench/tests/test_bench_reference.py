@@ -42,3 +42,32 @@ def test_rff_recipe_matches_the_client_library():
     feats = reference.rff_features64(X, W, c)
     assert feats.shape == (5, 32)
     assert np.allclose(feats, np.sqrt(2 / 32) * np.cos(X @ W + c))
+
+
+@pytest.mark.parametrize("cg", [False, True], ids=["factor", "gradients"])
+def test_ridge64_solve_many_matches_solve(cg, monkeypatch):
+    rng = np.random.default_rng(3)
+    A, b = rng.normal(size=(400, 64)), rng.normal(size=400)
+    r = reference.Ridge64(64)
+    r.add(A, b)
+    if cg:
+        monkeypatch.setattr(reference.Ridge64, "CG_MIN_DIM", 1)
+        monkeypatch.setattr(r, "solve", None)   # every answer by gradients
+    sigmas = [1.0, 0.01, 100.0, 0.01]
+    out = r.solve_many(sigmas)
+    assert sorted(out) == [0.01, 1.0, 100.0]
+    for sigma, w in out.items():
+        want = np.linalg.solve(A.T @ A + sigma * np.eye(64), A.T @ b)
+        assert np.allclose(w, want, rtol=1e-11, atol=1e-12)
+        assert r.residual(w, sigma) < 1e-12
+
+
+def test_ridge64_solve_many_falls_back_to_the_factor(monkeypatch):
+    rng = np.random.default_rng(4)
+    A, b = rng.normal(size=(20, 64)), rng.normal(size=20)   # rank 20 of 64
+    r = reference.Ridge64(64)
+    r.add(A, b)
+    monkeypatch.setattr(reference.Ridge64, "CG_MIN_DIM", 1)
+    monkeypatch.setattr(reference.Ridge64, "CG_MAX_ITER", 2)
+    out = r.solve_many([1e-6])
+    assert np.allclose(out[1e-6], r.solve(1e-6), rtol=1e-10, atol=1e-12)

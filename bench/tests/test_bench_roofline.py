@@ -32,3 +32,24 @@ def test_share_is_bound_by_the_slower_side():
     assert roofline.share(1.0, 819e6, 2e-3, PEAK) == pytest.approx(50.0)
     # 197 GFLOP at 197 TFLOP/s is 1 ms as well.
     assert roofline.share(197e9, 1.0, 4e-3, PEAK) == pytest.approx(25.0)
+
+
+def test_sharded_tri_solve_counts():
+    flops, nbytes = roofline.sharded_tri_solve(16384, 4)
+    assert flops == 2 * 16384 * 16384
+    # two passes over the lower triangle, wherever its tiles live, plus
+    # h, y and w on each of the 4 chips
+    assert nbytes == 4 * (16384 * 16385 + 3 * 16384 * 4)
+
+
+def test_sharded_tri_solve_share_stays_under_100():
+    d, chips = 16384, 4
+    flops, nbytes = roofline.sharded_tri_solve(d, chips)
+    # Fastest correct program: each chip streams only its part of the
+    # triangle and the vectors at peak bandwidth.
+    fastest = nbytes / PEAK["hbm_bytes_per_s"]
+    assert roofline.share(flops, nbytes, fastest, PEAK) == pytest.approx(100)
+    # The 2x2 block layout's way: every chip reads its whole (d/2)^2 tile
+    # once a pass; summed over the chips that is at least the triangle.
+    tiles = chips * 2 * 4 * (d // 2) ** 2 / PEAK["hbm_bytes_per_s"]
+    assert roofline.share(flops, nbytes, tiles, PEAK) <= 100.0

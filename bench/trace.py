@@ -9,7 +9,8 @@ program's (jitted function's) execution. Reduced here:
   * ``window_s``: the span of all events the trace holds, host and device;
   * ``modules`` / ``ops``: device seconds per program / per operation (a
     program's trailing ``(<id>)`` dropped; an operation named by its HLO
-    instruction, ``%gemm_nt_pallas.3``, not the whole instruction text);
+    instruction, ``%gemm_nt_pallas.3``, not the whole instruction text),
+    summed over the device planes, and how many planes ran each program;
   * ``gaps``: device idle time inside the window, attributed to the host
     span that overlapped each gap most (spans of the benchmark's own
     sessions, ``bench.*``, only where nothing else ran).
@@ -34,10 +35,16 @@ class Reduced:
     op_counts: dict[str, int]
     gaps: dict[str, float]
     devices: int
+    module_planes: dict[str, int] = dataclasses.field(default_factory=dict)
 
     def module_time(self, *needles: str) -> float:
         return sum(t for name, t in self.modules.items()
                    if any(n in name for n in needles))
+
+    def module_plane_count(self, *needles: str) -> int:
+        """Device planes that ran a matching program (the most of any)."""
+        return max((c for name, c in self.module_planes.items()
+                    if any(n in name for n in needles)), default=0)
 
     def module_count(self, *needles: str) -> int:
         return sum(c for name, c in self.module_counts.items()
@@ -85,9 +92,11 @@ def reduce(path: str | pathlib.Path) -> Reduced:
     op_counts: dict[str, int] = {}
     busy_all: list[tuple[int, int]] = []
     host: list[tuple[int, int, str]] = []
+    module_planes: dict[str, int] = {}
     for plane in pd.planes:
         is_device = plane.name.startswith("/device:")
         intervals: list[tuple[int, int]] = []
+        ran: set[str] = set()
         for line in plane.lines:
             for ev in line.events:
                 s, d = int(ev.start_ns), int(ev.duration_ns)
@@ -101,11 +110,14 @@ def reduce(path: str | pathlib.Path) -> Reduced:
                     name = _ID.sub("", ev.name)
                     modules[name] = modules.get(name, 0.0) + d * 1e-9
                     module_counts[name] = module_counts.get(name, 0) + 1
+                    ran.add(name)
                 elif line.name == "XLA Ops":
                     name = ev.name.split(" = ", 1)[0]
                     ops[name] = ops.get(name, 0.0) + d * 1e-9
                     op_counts[name] = op_counts.get(name, 0) + 1
                     intervals.append((s, s + d))
+        for name in ran:
+            module_planes[name] = module_planes.get(name, 0) + 1
         if is_device and intervals:
             merged = _merge(intervals)
             busy_total += sum(e - s for s, e in merged) * 1e-9
@@ -116,7 +128,7 @@ def reduce(path: str | pathlib.Path) -> Reduced:
                    modules=modules, module_counts=module_counts, ops=ops,
                    op_counts=op_counts,
                    gaps=_attribute_gaps(_merge(busy_all), host, t_min, t_max),
-                   devices=devices)
+                   devices=devices, module_planes=module_planes)
 
 
 def _attribute_gaps(busy, host, t_min, t_max) -> dict[str, float]:
